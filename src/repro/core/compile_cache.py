@@ -19,7 +19,7 @@ pre-decoded schedule columns, consulted by
 ``trace_engine._compile_cached``); both are backend/engine-independent,
 so those key components are fixed tags — backend/engine-*dependent*
 compiled artifacts are covered by JAX's own persistent compilation
-cache, which ``configure`` wires to a sibling directory when available.
+cache (``configure_jax_cache``).
 
 The cache is OPT-IN (tests and casual runs must not litter the
 filesystem): activate it with ``configure(path)`` or by exporting
@@ -28,6 +28,12 @@ wrong-version or otherwise unreadable entry is a MISS — the caller
 re-traces and overwrites the entry; the cache never raises into the
 launch path. ``stats()`` exposes hit/miss/error counters so tests and
 the cold-start benchmark can prove an entry was actually served.
+
+JAX's persistent compilation cache is a separate tier, switched on by the
+launch path (``configure_jax_cache``): it lives where
+``JAX_COMPILATION_CACHE_DIR`` says, and otherwise at ``DEFAULT_JAX_CACHE``,
+one fixed directory in the checkout (the path is part of what JAX's
+cache is keyed on, so it never moves).
 """
 from __future__ import annotations
 
@@ -36,9 +42,11 @@ import hashlib
 import os
 import pickle
 import tempfile
+from pathlib import Path
 
 _ENV = "EGPU_CACHE_DIR"
-_JAX_ENV = "EGPU_JAX_CACHE"     # set to 0 to skip wiring jax's own cache
+JAX_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_JAX_CACHE = str(Path(__file__).resolve().parents[3] / ".jax_cache")
 _FORMAT = 1
 _MAGIC = "egpu-compile-cache"
 
@@ -138,28 +146,30 @@ def key_for(kind: str, words, cfg, *, backend: str = "-",
 
 
 def configure(path: str | None) -> CompileCache | None:
-    """Activate the cache at ``path`` (None disables it). Also wires
-    JAX's persistent compilation cache to ``<path>/xla`` — covering the
-    backend/engine-dependent compiled artifacts — unless
-    ``EGPU_JAX_CACHE=0`` or the running jax can't."""
+    """Activate the cache at ``path`` (None disables it)."""
     global _active, _resolved
     _resolved = True
     if path is None:
         _active = None
         return None
     _active = CompileCache(path)
-    if os.environ.get(_JAX_ENV, "1").strip().lower() not in \
-            ("0", "false", "no", "off"):
-        try:
-            import jax
-
-            jax.config.update("jax_compilation_cache_dir",
-                              os.path.join(_active.path, "xla"))
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.0)
-        except Exception:
-            pass                         # jax cache unavailable: harmless
     return _active
+
+
+def configure_jax_cache() -> str:
+    """Give JAX's persistent compilation cache its directory; returns it.
+
+    ``JAX_COMPILATION_CACHE_DIR`` (which JAX reads itself) or a directory
+    already set through ``jax.config`` wins; otherwise the cache goes to
+    ``DEFAULT_JAX_CACHE``. Called by every launch, so importing the
+    package configures nothing."""
+    import jax
+
+    path = jax.config.jax_compilation_cache_dir
+    if not path:
+        path = DEFAULT_JAX_CACHE
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def active() -> CompileCache | None:

@@ -72,7 +72,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import isa, trace_engine
+from . import compile_cache, isa, trace_engine
 from .cycles import ProgramTrace, program_trace
 from .isa import NUM_CLASSES, Op
 from .packing import PACKINGS, WavePacking, pack_waves
@@ -887,6 +887,7 @@ def launch(dcfg: DeviceConfig, program=None, grid=None,
             raise ValueError("block_ids must be non-negative")
     backend = backend or dcfg.backend
     mode = _resolve_schedule(schedule, dcfg, len(kernels))
+    compile_cache.configure_jax_cache()
 
     # ---- host dispatch latency (the launch-queue model) ------------------
     if queue_depth < 0:

@@ -153,6 +153,11 @@ def _setp_compare(cond, typ, a_u, b_u) -> jax.Array:
     b_i = jax.lax.bitcast_convert_type(b_u, _I32)
     a_f = jax.lax.bitcast_convert_type(a_u, _F32)
     b_f = jax.lax.bitcast_convert_type(b_u, _F32)
+    if isinstance(cond, int) and isinstance(typ, int):
+        # host-constant fields: only the taken compare (Pallas-friendly)
+        a, b = ((a_f, b_f) if typ == int(isa.Typ.FP32) else
+                (a_i, b_i) if typ == int(isa.Typ.INT32) else (a_u, b_u))
+        return _SETP_CMPS.get(cond, _setp_ge)(a, b)
     is_fp = typ == int(isa.Typ.FP32)
     is_int = typ == int(isa.Typ.INT32)
 
@@ -160,18 +165,24 @@ def _setp_compare(cond, typ, a_u, b_u) -> jax.Array:
         return jnp.where(is_fp, f(a_f, b_f),
                          jnp.where(is_int, f(a_i, b_i), f(a_u, b_u)))
 
-    eq = pick(lambda a, b: a == b)
-    lt = pick(lambda a, b: a < b)
-    le = pick(lambda a, b: a <= b)
-    gt = pick(lambda a, b: a > b)
-    ge = pick(lambda a, b: a >= b)
-    C = isa.Cond
-    return jnp.where(cond == int(C.EQ), eq,
-                     jnp.where(cond == int(C.NE), ~eq,
-                               jnp.where(cond == int(C.LT), lt,
-                                         jnp.where(cond == int(C.LE), le,
-                                                   jnp.where(cond == int(C.GT),
-                                                             gt, ge)))))
+    res = pick(_setp_ge)
+    for code, f in _SETP_CMPS.items():
+        res = jnp.where(cond == code, pick(f), res)
+    return res
+
+
+def _setp_ge(a, b):
+    return a >= b
+
+
+# SETP's compare per condition code; any other code is GE
+_SETP_CMPS = {
+    int(isa.Cond.EQ): lambda a, b: a == b,
+    int(isa.Cond.NE): lambda a, b: ~(a == b),
+    int(isa.Cond.LT): lambda a, b: a < b,
+    int(isa.Cond.LE): lambda a, b: a <= b,
+    int(isa.Cond.GT): lambda a, b: a > b,
+}
 
 
 # ---------------------------------------------------------------------------
@@ -234,21 +245,139 @@ class FusedRow:
                                # array (Pallas rejects captured consts)
 
 
+class XlaLanes:
+    """Lane-level steps of the execute stage, as XLA ops over
+    ``(n_sms, 512)`` tiles.
+
+    The fused rows (``_apply_row_cols``) and the DOT/SUM handler reach
+    across lanes only through these helpers. A Pallas kernel body passes
+    its own set (``kernels.simt_step.KernelLanes``) built from lane
+    rotations, which Mosaic lowers; both sets compute the same values in
+    the same order, so the results are equal bit for bit.
+    """
+
+    @staticmethod
+    def snoop(col, ext: int):
+        """Snooped operand: lane ``t`` reads ``col[ext*16 + t % 16]``."""
+        lane = jnp.arange(MAX_THREADS, dtype=_I32) % N_SP
+        return jnp.take(col, ext * N_SP + lane, axis=1)
+
+    @staticmethod
+    def trap(oob, bad):
+        """Fold a lane trap mask into the per-SM OOB flag."""
+        return oob | bad.any(axis=1)
+
+    @staticmethod
+    def wave_sum(x):
+        """Per-wavefront FP32 sum, accumulated from 0.0 in lane order.
+        Lane 0 of each wavefront holds its sum; other lanes are 0."""
+        x3 = x.reshape(x.shape[0], MAX_WAVES, N_SP)
+        acc = jnp.zeros(x3.shape[:2], x.dtype)
+        for j in range(N_SP):
+            acc = acc + x3[:, :, j]
+        return jnp.pad(acc[:, :, None], ((0, 0), (0, 0), (0, N_SP - 1))
+                       ).reshape(x.shape)
+
+    @staticmethod
+    def wave_any(m):
+        """Per-wavefront OR, held in lane 0 of each wavefront."""
+        a = m.reshape(m.shape[0], MAX_WAVES, N_SP).any(axis=2)
+        return jnp.pad(a[:, :, None], ((0, 0), (0, 0), (0, N_SP - 1))
+                       ).reshape(m.shape)
+
+    @staticmethod
+    def lane0(col, src: int):
+        """A tile whose lane 0 holds ``col[:, src]``."""
+        return col[:, src:src + 1]
+
+
+def rounding_fence(block_idx):
+    """A (n_sms, 1) uint32 zero the compiler cannot see through.
+
+    Each eGPU FP instruction rounds its own result, but XLA's CPU backend
+    contracts a multiply feeding an add into one fused multiply-add once
+    both sit in one fusion (the fused rows' constant masks fold away
+    every select in between), skipping the multiply's rounding. OR-ing an
+    FP result's bits with this zero ends that: block ids are below 2**31,
+    so ``bid >> 31`` is 0, a fact no compiler can derive. (XLA's
+    optimization barrier is removed before fusion and does not help.)"""
+    return jnp.asarray(block_idx).astype(_U32).reshape(-1, 1) >> 31
+
+
+def _fenced(x_f32, zero):
+    return jax.lax.bitcast_convert_type(
+        jax.lax.bitcast_convert_type(x_f32, _U32) | zero, _F32)
+
+
+def sfu_rsqrt(x_u, zero):
+    """The INVSQR unit: 1/sqrt of FP32 bits ``x_u``, as uint32 bits.
+
+    Built from integer ops, multiplies and subtracts only: a bit-level
+    first guess and three Newton steps, each rounded on its own through
+    ``zero`` (``rounding_fence``). Every backend computes exactly these
+    IEEE operations, so the result is the same bits in XLA on any
+    device and inside a Pallas kernel, where a library ``rsqrt`` (or a
+    divide) is implemented differently by each compiler and even by
+    the shape it is vectorised at. Within 1 ulp of 1/sqrt(x) on normal
+    inputs; +-0 (and subnormals, which both the CPU and the TPU flush)
+    -> +-inf, +inf -> 0, NaN or negative -> NaN."""
+    x = jax.lax.bitcast_convert_type(x_u, _F32)
+    y = jax.lax.bitcast_convert_type(
+        np.int32(0x5F375A86)
+        - (jax.lax.bitcast_convert_type(x, _I32) >> 1), _F32)
+    half = _fenced(x * np.float32(0.5), zero)
+    for _ in range(2):
+        t = _fenced(_fenced(half * y, zero) * y, zero)
+        y = _fenced(y * _fenced(np.float32(1.5) - t, zero), zero)
+    # last step as a small correction, so its rounding error is small
+    e = _fenced(np.float32(0.5) - _fenced(_fenced(half * y, zero) * y, zero),
+                zero)
+    y = _fenced(y + _fenced(y * e, zero), zero)
+    y = jnp.where(x == np.float32(np.inf), np.float32(0.0), y)
+    y = jnp.where(x == 0, jnp.where(x_u >> 31 == 0, np.float32(np.inf),
+                                    np.float32(-np.inf)), y)
+    y = jnp.where((x < 0) | (x != x), np.float32(np.nan), y)
+    return jax.lax.bitcast_convert_type(y, _U32)
+
+
+def _wave_terms(is_dot, a_u, b_u, zero):
+    """The per-lane DOT (``a*b``) or SUM (``a+b``) terms, each rounded
+    on its own before the wavefront sum. ``is_dot`` may be traced."""
+    a_f = jax.lax.bitcast_convert_type(a_u, _F32)
+    b_f = jax.lax.bitcast_convert_type(b_u, _F32)
+    if isinstance(is_dot, bool):
+        return _fenced(a_f * b_f if is_dot else a_f + b_f, zero)
+    return jnp.where(is_dot, _fenced(a_f * b_f, zero),
+                     _fenced(a_f + b_f, zero))
+
+
+def _wave_reduce(lanes, prod, lane_eff, cur):
+    """DOT/SUM over one tile: each wavefront's enabled terms reduce into
+    its lane 0 in lane order; a wavefront with no enabled lane keeps its
+    old lane-0 value. Every other lane keeps ``cur``."""
+    red = lanes.wave_sum(jnp.where(lane_eff, prod, 0.0))
+    head = (jax.lax.broadcasted_iota(_I32, cur.shape, 1) % N_SP) == 0
+    write = head & lanes.wave_any(lane_eff)
+    return jnp.where(write, jax.lax.bitcast_convert_type(red, _U32), cur)
+
+
 def _apply_row_cols(cfg, backend: "ExecBackend", row: FusedRow, cols,
                     shmem, oob, block_idx, prog_idx,
-                    shmem_depth: int | None):
+                    shmem_depth: int | None, lanes=XlaLanes):
     """One fused row over UNPACKED register columns.
 
-    ``cols`` is the mutable list of 16 per-register (n_sms, 512) tiles.
-    This is the same data path as the matching ``make_data_handlers``
-    handler — same backend seam ops (``backend.alu``/``lod``/``sto``),
-    same mask/clip/trap formulas — specialized for host-constant fields:
-    a register write is a zero-copy column rebinding instead of a
+    ``cols`` is the mutable list of 16 per-register (n_sms, 512) tiles;
+    ``block_idx``/``prog_idx`` are (n_sms, 1) uint32 columns. This is the
+    same data path as the matching ``make_data_handlers`` handler — same
+    backend seam ops (``backend.alu``/``lod``/``sto``), same
+    mask/clip/trap formulas — specialized for host-constant fields: a
+    register write is a zero-copy column rebinding instead of a
     (n_sms, 512, 16) scatter, a no-snoop operand read is the column
     itself instead of a dynamic gather, and the select chains collapse
     to the one taken branch (which computes the identical values).
-    Bit-identity vs the packed handlers is pinned by the engine
-    conformance matrix.
+    ``lanes`` supplies the cross-lane steps (``XlaLanes`` here, the
+    rotation-based set inside a Pallas kernel). Bit-identity vs the
+    packed handlers is pinned by the engine conformance matrix.
     """
     from .isa import Typ
 
@@ -259,11 +388,11 @@ def _apply_row_cols(cfg, backend: "ExecBackend", row: FusedRow, cols,
     imm = int(d["imm"])
     snoop = int(d["x"]) == 1
     n_sms = cols[0].shape[0]
-    # the traced mask and snoop indices are rebuilt from iota comparisons
-    # against Python-int fields: XLA folds them to constants at compile
-    # time, and a Pallas kernel tracing this body captures no constant
-    # arrays (which pallas_call rejects)
-    tid_t = jnp.arange(MAX_THREADS, dtype=_I32)
+    # the traced mask is rebuilt from iota comparisons against Python-int
+    # fields: XLA folds them to constants at compile time, and a Pallas
+    # kernel tracing this body captures no constant arrays (which
+    # pallas_call rejects). The iota is 2-D: Mosaic has no 1-D vectors.
+    tid_t = jax.lax.broadcasted_iota(_I32, (1, MAX_THREADS), 1)
     lane_t = tid_t % N_SP
     active = ((lane_t < row.act_wthreads)
               & (tid_t // N_SP < row.act_waves)
@@ -280,7 +409,7 @@ def _apply_row_cols(cfg, backend: "ExecBackend", row: FusedRow, cols,
         psel = (cols[int(d["preg"])] & 1) != 0             # (n_sms, 512)
         if int(d.get("pneg", 0)):
             psel = ~psel
-        eff = active[None] & psel
+        eff = active & psel
     else:
         psel = None
         eff = active
@@ -289,7 +418,7 @@ def _apply_row_cols(cfg, backend: "ExecBackend", row: FusedRow, cols,
         # snoop (X=1) gathers regs[ext*16 + lane]; without it the
         # operand IS the register column — no gather at all
         if snoop:
-            return jnp.take(cols[r], int(ext) * N_SP + lane_t, axis=1)
+            return lanes.snoop(cols[r], int(ext))
         return cols[r]
 
     def addr_of():
@@ -301,6 +430,8 @@ def _apply_row_cols(cfg, backend: "ExecBackend", row: FusedRow, cols,
         old = cols[rd]
         mask = jnp.broadcast_to(eff, old.shape)
         cols[rd] = backend.alu(d["opcode"], d["typ"], a_u, b_u, mask, old)
+        if typ == int(Typ.FP32):
+            cols[rd] = cols[rd] | rounding_fence(block_idx)
     elif sel == 2:                                         # LOD
         depth = shmem_depth if shmem_depth is not None else shmem.shape[1]
         addr = addr_of()
@@ -308,13 +439,13 @@ def _apply_row_cols(cfg, backend: "ExecBackend", row: FusedRow, cols,
         safe = jnp.clip(addr, 0, depth - 1)
         mask = eff & ~bad
         cols[rd] = backend.lod(shmem, safe, mask, cols[rd])
-        oob = oob | bad.any(axis=1)
+        oob = lanes.trap(oob, bad)
     elif sel == 3:                                         # STO
         depth = shmem_depth if shmem_depth is not None else shmem.shape[1]
         addr = addr_of()
         bad = eff & ((addr < 0) | (addr >= depth))
         shmem = backend.sto(shmem, addr, cols[rd], eff & ~bad)
-        oob = oob | bad.any(axis=1)
+        oob = lanes.trap(oob, bad)
     elif sel == 4:                                         # LODI
         if typ == int(Typ.FP32):
             val = int(np.float32(imm).view(np.uint32))     # host bitcast
@@ -324,49 +455,32 @@ def _apply_row_cols(cfg, backend: "ExecBackend", row: FusedRow, cols,
         cols[rd] = jnp.where(eff, vals, cols[rd])
     elif sel == 5:                                         # TDX/TDY/BID/PID
         if op == int(Op.TDX):
-            vals = jnp.broadcast_to((tid_t % cfg.dim_x).astype(_U32)[None],
-                                    (n_sms, MAX_THREADS))
+            vals = (tid_t % cfg.dim_x).astype(_U32)
         elif op == int(Op.TDY):
-            vals = jnp.broadcast_to(
-                (tid_t // cfg.dim_x).astype(_U32)[None],
-                (n_sms, MAX_THREADS))
+            vals = (tid_t // cfg.dim_x).astype(_U32)
         elif op == int(Op.BID):
-            vals = jnp.broadcast_to(block_idx.astype(_U32)[:, None],
-                                    (n_sms, MAX_THREADS))
+            vals = block_idx
         else:
-            vals = jnp.broadcast_to(prog_idx.astype(_U32)[:, None],
-                                    (n_sms, MAX_THREADS))
-        cols[rd] = jnp.where(eff, vals, cols[rd])
+            vals = prog_idx
+        cols[rd] = jnp.where(eff, jnp.broadcast_to(vals, cols[rd].shape),
+                             cols[rd])
     elif sel == 6:                                         # DOT/SUM
+        # predicated-off lanes contribute nothing; a wavefront with no
+        # enabled lane keeps its old lane-0 value
         a_u, b_u = read(ra, d["ext_a"]), read(rb, d["ext_b"])
-        a2 = jax.lax.bitcast_convert_type(a_u, _F32) \
-            .reshape(n_sms, MAX_WAVES, N_SP)
-        b2 = jax.lax.bitcast_convert_type(b_u, _F32) \
-            .reshape(n_sms, MAX_WAVES, N_SP)
-        prod = a2 * b2 if op == int(Op.DOT) else a2 + b2
-        dest = jnp.arange(MAX_WAVES, dtype=_I32) * N_SP    # lane 0 per wave
-        cur = cols[rd][:, ::N_SP]
-        if pen:
-            # predicated-off lanes contribute nothing; a wavefront with
-            # no enabled lane keeps its old lane-0 value
-            lane_eff = eff.reshape(n_sms, MAX_WAVES, N_SP)
-            red = jnp.sum(jnp.where(lane_eff, prod, 0.0), axis=2)
-            new = jnp.where(lane_eff.any(axis=2),
-                            jax.lax.bitcast_convert_type(red, _U32), cur)
-        else:
-            lane_active = active.reshape(MAX_WAVES, N_SP)
-            red = jnp.sum(jnp.where(lane_active[None], prod, 0.0), axis=2)
-            new = jnp.where(lane_active.any(axis=1)[None],
-                            jax.lax.bitcast_convert_type(red, _U32), cur)
-        cols[rd] = cols[rd].at[:, dest].set(new)
+        lane_eff = jnp.broadcast_to(eff, a_u.shape)
+        prod = _wave_terms(op == int(Op.DOT), a_u, b_u,
+                           rounding_fence(block_idx))
+        cols[rd] = _wave_reduce(lanes, prod, lane_eff, cols[rd])
     elif sel == 7:                                         # SFU (INVSQR)
         src = int(d["ext_a"]) * N_SP if snoop else 0
-        val = jax.lax.bitcast_convert_type(cols[ra][:, src], _F32)
-        new = jax.lax.bitcast_convert_type(jax.lax.rsqrt(val), _U32)
+        new = sfu_rsqrt(lanes.lane0(cols[ra], src), rounding_fence(block_idx))
+        write = tid_t == 0
         if pen:
             # the SFU issues from thread 0: its predicate gates the write
-            new = jnp.where(psel[:, 0], new, cols[rd][:, 0])
-        cols[rd] = cols[rd].at[:, 0].set(new)
+            write = write & psel
+        cols[rd] = jnp.where(write, jnp.broadcast_to(new, cols[rd].shape),
+                             cols[rd])
     elif sel == 10:                                        # SETP
         a_u, b_u = read(ra, d["ext_a"]), read(rb, d["ext_b"])
         res = _setp_compare(imm, typ, a_u, b_u)
@@ -401,11 +515,17 @@ def apply_segment_rows(cfg, backend: "ExecBackend", rows, block_idx,
     (``kernels.simt_step.simt_segment``).
     """
     cols = [regs[:, :, r] for r in range(regs.shape[2])]
+    bid, pid = _sm_column(block_idx), _sm_column(prog_idx)
     for r in rows:
         cols, shmem, oob = _apply_row_cols(cfg, backend, r, cols, shmem,
-                                           oob, block_idx, prog_idx,
-                                           shmem_depth)
+                                           oob, bid, pid, shmem_depth)
     return jnp.stack(cols, axis=2), shmem, oob
+
+
+def _sm_column(idx):
+    """A per-SM index vector as the (n_sms, 1) uint32 column the fused
+    rows broadcast across lanes."""
+    return jnp.asarray(idx).astype(_U32)[:, None]
 
 
 # ---------------------------------------------------------------------------
@@ -471,7 +591,7 @@ def _fold_row(cfg, row: FusedRow, const_cols, depth: int) -> np.ndarray:
     these elementwise/reduce ops, so folding is bit-exact."""
     cols = [jnp.asarray(c)[None] if c is not None
             else jnp.zeros((1, MAX_THREADS), _U32) for c in const_cols]
-    z = jnp.zeros((1,), _I32)
+    z = jnp.zeros((1, 1), _U32)
     cols, _, _ = _apply_row_cols(
         cfg, get_execute_backend("inline"), row, cols,
         jnp.zeros((1, 1), _U32), jnp.zeros((1,), jnp.bool_), z, z, depth)
@@ -595,6 +715,7 @@ def apply_segment_residual(cfg, backend: "ExecBackend", seg: FusedSegment,
     module comment above ``FusedSegment``)."""
     n = regs.shape[0]
     cols = [regs[:, :, r] for r in range(regs.shape[2])]
+    bid, pid = _sm_column(block_idx), _sm_column(prog_idx)
 
     def mat(v):
         return jnp.broadcast_to(jnp.asarray(v)[None], (n, MAX_THREADS))
@@ -604,8 +725,7 @@ def apply_segment_residual(cfg, backend: "ExecBackend", seg: FusedSegment,
             cols[r] = mat(v)
         if kind == "exec":
             cols, shmem, oob = _apply_row_cols(
-                cfg, backend, row, cols, shmem, oob, block_idx, prog_idx,
-                shmem_depth)
+                cfg, backend, row, cols, shmem, oob, bid, pid, shmem_depth)
         elif kind == "lod":
             safe, mask, bad_any = data
             rd = int(row.d["rd"])
@@ -767,8 +887,9 @@ def _pallas_alu(op, typ, a, b, mask, old) -> jax.Array:
     from ..kernels.simt_alu import simt_alu
 
     n_sm = a.shape[0]
-    # largest tile that divides the batch, capped at 8 SMs (80 KiB VMEM)
-    block_sm = max(d for d in range(1, min(8, n_sm) + 1) if n_sm % d == 0)
+    # 8-SM tiles (80 KiB VMEM) where they divide the batch, else the whole
+    # batch in one block (a TPU block tiles 8 sublanes or spans the array)
+    block_sm = 8 if n_sm % 8 == 0 else n_sm
     return simt_alu(op.astype(_I32), typ.astype(_I32), a, b,
                     mask.astype(_U32), old,
                     interpret=ops.interpret_mode(), block_sm=block_sm)
@@ -968,30 +1089,21 @@ def make_data_handlers(cfg, backend: ExecBackend, d: dict,
         # Predicated-off lanes contribute nothing and a wavefront with no
         # enabled lane keeps its old lane-0 value.
         regs, shmem, gmem, oob = s
-        n_sms = regs.shape[0]
         a_u, b_u = operands(regs)
-        lane_eff = eff(regs).reshape(n_sms, MAX_WAVES, N_SP)
-        a2 = jax.lax.bitcast_convert_type(a_u, _F32) \
-            .reshape(n_sms, MAX_WAVES, N_SP)
-        b2 = jax.lax.bitcast_convert_type(b_u, _F32) \
-            .reshape(n_sms, MAX_WAVES, N_SP)
-        prod = jnp.where(op == int(Op.DOT), a2 * b2, a2 + b2)
-        red = jnp.sum(jnp.where(lane_eff, prod, 0.0), axis=2)
-        wave_active = lane_eff.any(axis=2)                  # (n_sms, waves)
-        dest = jnp.arange(MAX_WAVES, dtype=_I32) * N_SP     # lane 0 per wave
-        cur = regs[:, dest, d["rd"]]                        # (n_sms, waves)
-        new = jnp.where(wave_active,
-                        jax.lax.bitcast_convert_type(red, _U32), cur)
-        return regs.at[:, dest, d["rd"]].set(new), shmem, gmem, oob
+        lane_eff = eff(regs)
+        cur = col(regs, d["rd"])
+        prod = _wave_terms(op == int(Op.DOT), a_u, b_u,
+                           rounding_fence(block_idx))
+        new = _wave_reduce(XlaLanes, prod, lane_eff, cur)
+        return set_col(regs, d["rd"], new), shmem, gmem, oob
 
     def h_sfu(s):
         # single-lane SFU: 1/sqrt of wavefront-0 lane-0 (snoopable source);
         # the issuing thread-0 predicate gates the write
         regs, shmem, gmem, oob = s
         src_tid = jnp.where(snoop, d["ext_a"] * N_SP, 0)
-        val = jax.lax.bitcast_convert_type(
-            regs[:, src_tid, d["ra"]], _F32)                # (n_sms,)
-        r = jax.lax.bitcast_convert_type(jax.lax.rsqrt(val), _U32)
+        r = sfu_rsqrt(regs[:, src_tid, d["ra"]],            # (n_sms,)
+                      rounding_fence(block_idx)[:, 0])
         new = jnp.where(pgate(regs)[:, 0], r, regs[:, 0, d["rd"]])
         return regs.at[:, 0, d["rd"]].set(new), shmem, gmem, oob
 

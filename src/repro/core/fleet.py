@@ -61,7 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import trace_engine
+from . import compile_cache, trace_engine
 from .cycles import ProgramTrace
 from .device import (
     _U32,
@@ -196,10 +196,8 @@ def _run_shard_map(fcfg: FleetConfig, backend: str, cfg, words,
     mesh axis; each simulated eGPU runs its contiguous block slice on
     its own XLA device, waves of ``n_sms`` back to back against its own
     gmem replica. Returns device-major stacked
-    ``(order, regs, shmem, gmems, oob, halted)``."""
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
-
+    ``(order, regs, shmem, gmems, oob, halted, devices)``, ``devices``
+    naming the JAX device that ran each simulated device."""
     from ..launch.mesh import make_fleet_mesh
     from ..launch.shardings import fleet_spec
 
@@ -240,11 +238,17 @@ def _run_shard_map(fcfg: FleetConfig, backend: str, cfg, words,
             oob = oob.at[w0:w1].set(o)
         return regs[None], sh[None], gmem[None], oob[None]
 
-    regs, sh, gmems, oob = shard_map(
-        body, mesh=mesh,
-        in_specs=(spec,) * 6, out_specs=(spec,) * 4)(
+    # check_vma=False: the Pallas backend's kernels declare plain output
+    # shapes, with no varying-manual-axes annotation to check
+    regs, sh, gmems, oob = jax.shard_map(
+        body, mesh=mesh, in_specs=(spec,) * 6, out_specs=(spec,) * 4,
+        check_vma=False)(
             bid, pid, regs0, sh0, gm0, oob0)
-    return order, regs, sh, gmems, oob, sched.halted
+    # the JAX device holding each simulated device's slice, in fleet order
+    shards = sorted(regs.addressable_shards,
+                    key=lambda s: s.index[0].start or 0)
+    devices = [int(s.device.id) for s in shards]
+    return order, regs, sh, gmems, oob, sched.halted, devices
 
 
 def launch_fleet(fcfg: FleetConfig, program=None, grid=None,
@@ -298,6 +302,7 @@ def launch_fleet(fcfg: FleetConfig, program=None, grid=None,
     n_blocks = int(gmap.shape[0])
     backend = backend or dcfg.backend
     mode = _resolve_schedule(schedule, dcfg, len(kernels))
+    compile_cache.configure_jax_cache()
     names, cfgs, imems, traces, word_arrays = _lower_kernels(dcfg, kernels)
     eng, eng_fallback = _resolve_engine(engine, dcfg, traces)
 
@@ -347,8 +352,10 @@ def launch_fleet(fcfg: FleetConfig, program=None, grid=None,
     halted = True
     shmem_pad = dcfg.sm.shmem_depth
     sub_engine = eng
+    shard_devices = None
     if placement == "shard_map":
-        order, regs_d, sh_d, gmems_d, oob_d, sm_halted = _run_shard_map(
+        (order, regs_d, sh_d, gmems_d, oob_d, sm_halted,
+         shard_devices) = _run_shard_map(
             fcfg, backend, cfgs[0], word_arrays[0], gmap, local_bid,
             device_of, sh_batches[0], gm)
         # per-device replicas diff-merge against the launch image in
@@ -484,6 +491,8 @@ def launch_fleet(fcfg: FleetConfig, program=None, grid=None,
         "remote_gmem_cycles": int(remote_gmem_cycles),
         "per_device": per_device,
     }
+    if shard_devices is not None:
+        fleet_info["shard_devices"] = shard_devices
 
     return LaunchResult(
         grid=(n_blocks,),

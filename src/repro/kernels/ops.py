@@ -1,29 +1,12 @@
 """Public jit'd entry points for the kernel layer.
 
-Pallas interpret mode is a *setting*, not a constant: the kernels TARGET
-TPU/GPU and are validated via the Pallas interpreter against the
-``ref.py`` oracles on CPU. Resolution order for whether a kernel runs
-interpreted:
-
-  1. an explicit ``interpret=`` argument at the call site;
-  2. ``set_interpret(True|False|None)`` — process-wide programmatic
-     override (None restores auto-detection);
-  3. the ``EGPU_PALLAS_INTERPRET`` environment variable: ``1/true/yes``
-     forces interpret mode, ``0/false/no`` forces compiled Pallas,
-     ``auto`` (or unset) defers to platform detection;
-  4. platform auto-detection: interpret everywhere except on a real
-     TPU/GPU backend, where the compiled path is the point.
-
-``INTERPRET`` is kept as the import-time auto-detected default for
-backward compatibility; new code should call ``interpret_mode()``, which
-re-resolves the setting on every call so the compiled (non-interpret)
-path is reachable without editing source — set
-``EGPU_PALLAS_INTERPRET=0`` (or call ``set_interpret(False)``) on a
-machine with a real accelerator.
+The kernels target the TPU. Off the TPU they run under the Pallas
+interpreter against the ``ref.py`` oracles: ``interpret_mode()`` is true
+exactly when JAX's default backend is the CPU. It is resolved at each
+call, never at import, so importing this package initialises no backend.
+An explicit ``interpret=`` argument at a call site still wins.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -35,50 +18,10 @@ from .mgs_qrd import mgs_qrd
 from .simt_alu import simt_alu
 from .wavefront_dot import wavefront_dot
 
-_ENV = "EGPU_PALLAS_INTERPRET"
-_TRUTHY = ("1", "true", "yes", "on")
-_FALSY = ("0", "false", "no", "off")
-
-# process-wide programmatic override (None = defer to env / platform)
-_override: bool | None = None
-
-
-def _platform_default() -> bool:
-    """Interpret everywhere the compiled Pallas path can't run: only a
-    real TPU/GPU backend lowers these kernels natively."""
-    return jax.default_backend() not in ("tpu", "gpu")
-
-
-def set_interpret(value: bool | None) -> None:
-    """Force (True/False) or restore auto-detection (None) process-wide.
-
-    Takes precedence over ``EGPU_PALLAS_INTERPRET``; explicit
-    ``interpret=`` call-site arguments still win.
-    """
-    global _override
-    _override = None if value is None else bool(value)
-
 
 def interpret_mode() -> bool:
-    """Resolve the current interpret setting (override > env > platform)."""
-    if _override is not None:
-        return _override
-    env = os.environ.get(_ENV, "").strip().lower()
-    if env in _TRUTHY:
-        return True
-    if env in _FALSY:
-        return False
-    if env and env != "auto":
-        raise ValueError(
-            f"{_ENV}={env!r} must be one of 1/0/true/false/yes/no/on/off/"
-            f"auto")
-    return _platform_default()
-
-
-# import-time auto-detected default, kept for back-compat with code that
-# reads/sets ``ops.INTERPRET`` directly (the executor now resolves via
-# ``interpret_mode()`` per call instead)
-INTERPRET = _platform_default()
+    """Whether Pallas kernels run interpreted: on the CPU backend only."""
+    return jax.default_backend() == "cpu"
 
 
 def alu(op, typ, a, b, mask, old, **kw):
@@ -108,5 +51,4 @@ def flash(q, k, v, **kw):
     return flash_attention(q, k, v, **kw)
 
 
-__all__ = ["alu", "dot", "qrd", "fft", "flash", "ref", "INTERPRET",
-           "interpret_mode", "set_interpret"]
+__all__ = ["alu", "dot", "qrd", "fft", "flash", "ref", "interpret_mode"]

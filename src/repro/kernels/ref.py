@@ -22,26 +22,71 @@ def _sext16(x):
     return low | (((low >> 15) & 1) * jnp.uint32(0xFFFF0000))
 
 
+def _static(v):
+    """``v`` as a Python int when it is a host constant, else None."""
+    if isinstance(v, (int, np.integer)):
+        return int(v)
+    if isinstance(v, np.ndarray) and v.shape == ():
+        return int(v)
+    return None
+
+
+def _mul16(typ, a, b):
+    """16x16 -> 32-bit multiply, signed unless ``typ`` is UINT32."""
+    def uint():
+        return (a & 0xFFFF) * (b & 0xFFFF)
+
+    def sint():
+        return _sext16(a) * _sext16(b)
+
+    s_typ = _static(typ)
+    if s_typ is not None:
+        return uint() if s_typ == TYP_UINT32 else sint()
+    return jnp.where(typ == TYP_UINT32, uint(), sint())
+
+
+# integer-path result of each opcode; any other opcode is LSR
+_INT_OPS = {
+    ALU_ADD: lambda typ, a, b: a + b,
+    ALU_SUB: lambda typ, a, b: a - b,
+    ALU_MUL: _mul16,
+    ALU_AND: lambda typ, a, b: a & b,
+    ALU_OR: lambda typ, a, b: a | b,
+    ALU_XOR: lambda typ, a, b: a ^ b,
+    ALU_NOT: lambda typ, a, b: ~a,
+    ALU_LSL: lambda typ, a, b: a << (b & 31),
+}
+_FP_OPS = {ALU_ADD: lambda a, b: a + b, ALU_SUB: lambda a, b: a - b,
+           ALU_MUL: lambda a, b: a * b}
+
+
+def _lsr(typ, a, b):
+    return a >> (b & 31)
+
+
 def alu_ref(op: jax.Array, typ: jax.Array, a_u32: jax.Array,
             b_u32: jax.Array) -> jax.Array:
-    """eGPU SIMT ALU semantics on uint32 lanes (any shape)."""
+    """eGPU SIMT ALU semantics on uint32 lanes (any shape).
+
+    ``op``/``typ`` may be traced scalars or host constants; constants
+    compute only the taken branch. The select chain is nested
+    ``where``s (``jnp.select`` lowers through an argmax Mosaic lacks),
+    so the same function runs inside Pallas TPU kernels."""
     a_f = jax.lax.bitcast_convert_type(a_u32, jnp.float32)
     b_f = jax.lax.bitcast_convert_type(b_u32, jnp.float32)
-    add_u = a_u32 + b_u32
-    sub_u = a_u32 - b_u32
-    mul_int = _sext16(a_u32) * _sext16(b_u32)
-    mul_uint = (a_u32 & 0xFFFF) * (b_u32 & 0xFFFF)
-    mul_u = jnp.where(typ == TYP_UINT32, mul_uint, mul_int)
-    sh = b_u32 & 31
-    res_int = jnp.select(
-        [op == ALU_ADD, op == ALU_SUB, op == ALU_MUL, op == ALU_AND,
-         op == ALU_OR, op == ALU_XOR, op == ALU_NOT, op == ALU_LSL],
-        [add_u, sub_u, mul_u, a_u32 & b_u32, a_u32 | b_u32, a_u32 ^ b_u32,
-         ~a_u32, a_u32 << sh],
-        a_u32 >> sh)
-    res_fp = jax.lax.bitcast_convert_type(jnp.select(
-        [op == ALU_ADD, op == ALU_SUB], [a_f + b_f, a_f - b_f], a_f * b_f),
-        jnp.uint32)
+    s_op, s_typ = _static(op), _static(typ)
+    if s_op is not None and s_typ is not None:
+        if s_typ == TYP_FP32 and s_op in _FP_OPS:
+            return jax.lax.bitcast_convert_type(_FP_OPS[s_op](a_f, b_f),
+                                                jnp.uint32)
+        return _INT_OPS.get(s_op, _lsr)(s_typ, a_u32, b_u32)
+    res_int = _lsr(typ, a_u32, b_u32)
+    for code, fn in _INT_OPS.items():
+        res_int = jnp.where(op == code, fn(typ, a_u32, b_u32), res_int)
+    res_fp = _FP_OPS[ALU_MUL](a_f, b_f)
+    for code in (ALU_SUB, ALU_ADD):
+        res_fp = jnp.where(op == code, _FP_OPS[code](a_f, b_f), res_fp)
+    res_fp = jax.lax.bitcast_convert_type(res_fp, jnp.uint32)
     fp_op = (typ == TYP_FP32) & ((op == ALU_ADD) | (op == ALU_SUB)
                                  | (op == ALU_MUL))
     return jnp.where(fp_op, res_fp, res_int)

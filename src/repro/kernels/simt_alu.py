@@ -9,7 +9,9 @@ the VPU, with the flexible-ISA thread mask applied in-kernel.
 
 Operands arrive pre-gathered (register-file column reads are a gather the
 XLA scatter/gather units handle better than a Pallas minor-dim dynamic
-index); the kernel is the execute stage.
+index); the kernel is the execute stage. The decoded ``op``/``typ`` ride
+in SMEM as scalar-prefetch operands, and the body is ``ref.alu_ref`` —
+the same formulas as the inline backend.
 """
 from __future__ import annotations
 
@@ -18,75 +20,43 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .ref import (
-    ALU_ADD,
-    ALU_AND,
-    ALU_LSL,
-    ALU_MUL,
-    ALU_NOT,
-    ALU_OR,
-    ALU_SUB,
-    ALU_XOR,
-    TYP_FP32,
-    TYP_UINT32,
-    _sext16,
-)
+from .ref import alu_ref
 
 N_THREADS = 512
 
 
 def _alu_kernel(opv_ref, a_ref, b_ref, mask_ref, old_ref, out_ref):
-    op = opv_ref[0]
-    typ = opv_ref[1]
-    a = a_ref[...]
-    b = b_ref[...]
-    a_f = jax.lax.bitcast_convert_type(a, jnp.float32)
-    b_f = jax.lax.bitcast_convert_type(b, jnp.float32)
-
-    mul_int = _sext16(a) * _sext16(b)
-    mul_uint = (a & 0xFFFF) * (b & 0xFFFF)
-    sh = b & 31
-    res_int = jnp.select(
-        [op == ALU_ADD, op == ALU_SUB, op == ALU_MUL, op == ALU_AND,
-         op == ALU_OR, op == ALU_XOR, op == ALU_NOT, op == ALU_LSL],
-        [a + b, a - b,
-         jnp.where(typ == TYP_UINT32, mul_uint, mul_int),
-         a & b, a | b, a ^ b, ~a, a << sh],
-        a >> sh)
-    res_fp = jax.lax.bitcast_convert_type(
-        jnp.select([op == ALU_ADD, op == ALU_SUB],
-                   [a_f + b_f, a_f - b_f], a_f * b_f), jnp.uint32)
-    fp_op = (typ == TYP_FP32) & ((op == ALU_ADD) | (op == ALU_SUB)
-                                 | (op == ALU_MUL))
-    res = jnp.where(fp_op, res_fp, res_int)
-    # flexible-ISA: inactive threads keep their old destination value
+    res = alu_ref(opv_ref[0], opv_ref[1], a_ref[...], b_ref[...])
     out_ref[...] = jnp.where(mask_ref[...] != 0, res, old_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_sm"))
 def simt_alu(op: jax.Array, typ: jax.Array, a: jax.Array, b: jax.Array,
-             mask: jax.Array, old: jax.Array, *, interpret: bool = True,
+             mask: jax.Array, old: jax.Array, *, interpret: bool,
              block_sm: int = 8) -> jax.Array:
     """Execute one ALU instruction on (n_sm, 512) uint32 operand tiles.
 
     block_sm SMs per grid step: a (block_sm, 512) uint32 tile is
     block_sm * 2 KiB of VMEM per operand — 5 operands x 8 SMs = 80 KiB,
-    comfortably inside a v5e core's VMEM.
+    comfortably inside a v5e core's VMEM. A block is the whole batch or a
+    multiple of 8 SMs (the TPU's sublane tiling).
     """
     n_sm = a.shape[0]
     block_sm = min(block_sm, n_sm)
     if n_sm % block_sm:
         raise ValueError(f"n_sm={n_sm} must be a multiple of block_sm={block_sm}")
-    opv = jnp.stack([op.astype(jnp.int32), typ.astype(jnp.int32)])
-    grid = (n_sm // block_sm,)
-    spec = pl.BlockSpec((block_sm, N_THREADS), lambda i: (i, 0))
+    if block_sm != n_sm and block_sm % 8:
+        raise ValueError(f"block_sm={block_sm} must be a multiple of 8 "
+                         f"or the whole batch of {n_sm} SMs")
+    opv = jnp.stack([jnp.asarray(op, jnp.int32), jnp.asarray(typ, jnp.int32)])
+    spec = pl.BlockSpec((block_sm, N_THREADS), lambda i, opv: (i, 0))
     return pl.pallas_call(
         _alu_kernel,
         out_shape=jax.ShapeDtypeStruct((n_sm, N_THREADS), jnp.uint32),
-        grid=grid,
-        in_specs=[pl.BlockSpec((2,), lambda i: (0,)),
-                  spec, spec, spec, spec],
-        out_specs=spec,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(n_sm // block_sm,),
+            in_specs=[spec, spec, spec, spec], out_specs=spec),
         interpret=interpret,
     )(opv, a, b, mask.astype(jnp.uint32), old)

@@ -44,7 +44,10 @@ def make_fleet_mesh(n_devices: int):
             f"{len(jax.devices())}; set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={n_devices} "
             f"(CPU) or use placement='host'")
-    return jax.make_mesh((n_devices,), ("fleet",))
+    # Auto axes: the fleet body is a manual shard_map over "fleet", and
+    # its state carries no sharding in its types
+    return jax.make_mesh((n_devices,), ("fleet",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def data_axes(mesh) -> tuple[str, ...]:

@@ -24,7 +24,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -83,9 +82,9 @@ def pipeline_apply(mesh: Mesh, stage_fn: Callable, stage_params, x_mb,
         return outs
 
     spec_params = jax.tree_util.tree_map(lambda _: P(axis), stage_params)
-    fn = shard_map(per_stage, mesh=mesh,
-                   in_specs=(spec_params, P()), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(per_stage, mesh=mesh,
+                       in_specs=(spec_params, P()), out_specs=P(),
+                       check_vma=False)
     return fn(stage_params, x_mb)
 
 

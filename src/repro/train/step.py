@@ -87,7 +87,6 @@ def make_compressed_dp_step(model, rc: RunConfig, mesh, total_steps=10_000):
     shrinks 4x). Batch is sharded over the 'data' axis; params replicated.
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     n_data = mesh.shape["data"]
 
@@ -104,11 +103,11 @@ def make_compressed_dp_step(model, rc: RunConfig, mesh, total_steps=10_000):
 
     rep = P()  # replicated
     batch_spec = P("data")
-    smapped = shard_map(
+    smapped = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(rep, rep, rep, batch_spec),
         out_specs=(rep, rep, rep, rep),
-        check_rep=False)
+        check_vma=False)
 
     def step_fn(state: TrainState, batch):
         ef = state.ef if state.ef is not None \

@@ -7,6 +7,7 @@ from hypothesis import strategies as st
 from repro.core import assemble, check_hazards, disassemble
 from repro.core.assembler import AsmError, assemble_line
 from repro.core.isa import (
+    Cond,
     CONTROL_IMM_OPS,
     NUM_CLASSES,
     Depth,
@@ -43,6 +44,8 @@ def test_word_is_40_bits():
 def test_encode_decode_roundtrip_property(op, typ, rd, ra, rb, imm, width, depth):
     if op in (Op.JMP, Op.JSR, Op.LOOP, Op.INIT):
         imm = abs(imm)  # control addresses are unsigned
+    if op == Op.SETP:
+        imm %= len(Cond)  # SETP's immediate is its condition code
     ins = Instr(op=op, typ=typ, rd=rd, ra=ra, rb=rb, imm=imm,
                 width=width, depth=depth)
     dec = Instr.decode(ins.encode())

@@ -59,6 +59,33 @@ def test_simt_alu_fp_exactness():
     np.testing.assert_array_equal(got, af * bf)
 
 
+def test_interpret_mode_follows_platform(monkeypatch):
+    """Pallas runs interpreted exactly on the CPU backend, resolved at
+    each call; there is no switch that forces it either way."""
+    assert ops.interpret_mode() == (jax.default_backend() == "cpu")
+    for platform, want in (("cpu", True), ("tpu", False), ("gpu", False)):
+        monkeypatch.setattr(jax, "default_backend", lambda p=platform: p)
+        assert ops.interpret_mode() is want
+    assert not hasattr(ops, "set_interpret")
+    assert not hasattr(ops, "INTERPRET")
+
+
+def test_importing_kernels_initialises_no_backend():
+    """Importing the package claims no device, and ``simt_alu`` names
+    the kernel's module (the function lives inside it)."""
+    import os
+    import subprocess
+    import sys
+
+    code = ("import repro.kernels, repro.core\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge._backends, list(xla_bridge._backends)\n"
+            "assert callable(repro.kernels.simt_alu.simt_alu)\n")
+    src = os.path.join(os.path.dirname(__file__), os.pardir, "src")
+    subprocess.run([sys.executable, "-c", code], check=True,
+                   env={**os.environ, "PYTHONPATH": os.path.abspath(src)})
+
+
 # ---------------------------------------------------------------------------
 # wavefront_dot
 # ---------------------------------------------------------------------------

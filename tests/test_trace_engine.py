@@ -264,7 +264,6 @@ def persistent_cache(tmp_path, monkeypatch):
     from repro.core import compile_cache
     from repro.core.cycles import _trace_cached
 
-    monkeypatch.setenv("EGPU_JAX_CACHE", "0")   # keep jax's cache out
     cc = compile_cache.configure(str(tmp_path / "cache"))
     _trace_cached.cache_clear()                 # force disk consultation
     yield cc
@@ -338,6 +337,51 @@ def test_persistent_cache_disabled_without_configuration(tmp_path,
     assert compile_cache.load("deadbeef") is None
     compile_cache.store("deadbeef", 1)          # silent no-op
     assert compile_cache.stats() is None
+
+
+def _run_launch_reporting_cache_dir(env):
+    """Run one small launch in a fresh interpreter (JAX reads its cache
+    variable once, at import) with ``env`` as the only cache setting, and
+    return the cache directory it used."""
+    import os
+    import subprocess
+    import sys
+
+    code = ("import jax\n"
+            "from repro.core import DeviceConfig, assemble, launch\n"
+            "prog = assemble('TDX R1\\nSTO R1, (R1)+0\\nSTOP')\n"
+            "launch(DeviceConfig(), prog, grid=(1,), block=16)\n"
+            "print(jax.config.jax_compilation_cache_dir)\n")
+    src = os.path.join(os.path.dirname(__file__), os.pardir, "src")
+    env = {**{k: v for k, v in os.environ.items()
+              if k != "JAX_COMPILATION_CACHE_DIR"},
+           "PYTHONPATH": os.path.abspath(src), **env}
+    out = subprocess.run([sys.executable, "-c", code], env=env, check=True,
+                         capture_output=True, text=True).stdout
+    return out.strip().splitlines()[-1]
+
+
+def test_jax_cache_honours_env_dir(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, a launch leaves JAX's cache
+    there, and the compiled programs are written into it."""
+    cache = tmp_path / "jax-cache"
+    used = _run_launch_reporting_cache_dir(
+        {"JAX_COMPILATION_CACHE_DIR": str(cache),
+         "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0"})
+    assert used == str(cache)
+    assert any(cache.iterdir())
+
+
+def test_jax_cache_defaults_to_fixed_checkout_dir():
+    """Without the variable the launch path puts the cache in one fixed
+    directory of the checkout, the same in every process."""
+    from pathlib import Path
+
+    from repro.core import compile_cache
+
+    used = _run_launch_reporting_cache_dir({})
+    root = Path(__file__).resolve().parents[1]
+    assert used == compile_cache.DEFAULT_JAX_CACHE == str(root / ".jax_cache")
 
 
 def test_bogus_engine_rejected():
