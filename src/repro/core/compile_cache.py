@@ -32,8 +32,8 @@ the cold-start benchmark can prove an entry was actually served.
 JAX's persistent compilation cache is a separate tier, switched on by the
 launch path (``configure_jax_cache``): it lives where
 ``JAX_COMPILATION_CACHE_DIR`` says, and otherwise at ``DEFAULT_JAX_CACHE``,
-one fixed directory in the checkout (the path is part of what JAX's
-cache is keyed on, so it never moves).
+one fixed directory in the checkout, so a later process finds what an
+earlier one compiled.
 """
 from __future__ import annotations
 
@@ -45,7 +45,6 @@ import tempfile
 from pathlib import Path
 
 _ENV = "EGPU_CACHE_DIR"
-JAX_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 DEFAULT_JAX_CACHE = str(Path(__file__).resolve().parents[3] / ".jax_cache")
 _FORMAT = 1
 _MAGIC = "egpu-compile-cache"
@@ -156,8 +155,8 @@ def configure(path: str | None) -> CompileCache | None:
     return _active
 
 
-def configure_jax_cache() -> str:
-    """Give JAX's persistent compilation cache its directory; returns it.
+def configure_jax_cache() -> None:
+    """Give JAX's persistent compilation cache its directory.
 
     ``JAX_COMPILATION_CACHE_DIR`` (which JAX reads itself) or a directory
     already set through ``jax.config`` wins; otherwise the cache goes to
@@ -165,11 +164,8 @@ def configure_jax_cache() -> str:
     package configures nothing."""
     import jax
 
-    path = jax.config.jax_compilation_cache_dir
-    if not path:
-        path = DEFAULT_JAX_CACHE
-        jax.config.update("jax_compilation_cache_dir", path)
-    return path
+    if not jax.config.jax_compilation_cache_dir:
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_JAX_CACHE)
 
 
 def active() -> CompileCache | None:

@@ -216,15 +216,8 @@ def simt_scatter(mem: jax.Array, addr: jax.Array, vals: jax.Array,
 # ---------------------------------------------------------------------------
 #
 # The SM batch's lanes are flattened to one (1, n_sm * 512) row in
-# (sm, thread) order, which is also the port's drain order.
-
-def _gather_shared_kernel(mem_ref, addr_ref, mask_ref, old_ref, out_ref):
-    def read_row(c):
-        return mem_ref[pl.ds(c, 1), :]
-
-    vals = gather_words(read_row, mem_ref.shape[0], addr_ref[...])
-    out_ref[...] = jnp.where(mask_ref[...] != 0, vals, old_ref[...])
-
+# (sm, thread) order, which is also the port's drain order; the kernel
+# bodies are LOD's and STO's, over the whole batch in one block.
 
 def _flat_lanes(x: jax.Array) -> jax.Array:
     return jax.lax.bitcast_convert_type(x.astype(_U32), _I32).reshape(1, -1)
@@ -236,20 +229,11 @@ def simt_gather_shared(mem: jax.Array, addr: jax.Array, mask: jax.Array,
     """GLD gather: every SM's lanes read the one global segment."""
     n_sm = addr.shape[0]
     out = pl.pallas_call(
-        _gather_shared_kernel,
+        _gather_kernel,
         out_shape=jax.ShapeDtypeStruct((1, n_sm * N_THREADS), _I32),
         interpret=interpret,
     )(_to_rows(mem), _flat_lanes(addr), _flat_lanes(mask), _flat_lanes(old))
     return jax.lax.bitcast_convert_type(out, _U32).reshape(n_sm, N_THREADS)
-
-
-def _scatter_shared_kernel(mem_ref, addr_ref, vals_ref, do_ref, out_ref):
-    def write_row(c, row):
-        out_ref[pl.ds(c, 1), :] = row
-
-    scatter_words(lambda c: mem_ref[pl.ds(c, 1), :], write_row,
-                  mem_ref.shape[0], addr_ref[...], vals_ref[...],
-                  do_ref[...] != 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -259,7 +243,7 @@ def simt_scatter_shared(mem: jax.Array, addr: jax.Array, vals: jax.Array,
     (gdepth,) = mem.shape
     rows = _rows(gdepth)
     out = pl.pallas_call(
-        _scatter_shared_kernel,
+        _scatter_kernel,
         out_shape=jax.ShapeDtypeStruct((rows, CHUNK), _I32),
         interpret=interpret,
     )(_to_rows(mem), _flat_lanes(addr), _flat_lanes(vals), _flat_lanes(do))

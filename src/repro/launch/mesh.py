@@ -23,12 +23,17 @@ import jax
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Elastic variant: any shape whose product <= len(jax.devices())."""
-    return jax.make_mesh(shape, axes)
+    """Elastic variant: any shape whose product <= len(jax.devices()).
+
+    Every axis is Auto: the shardings come from ``launch/shardings.py``
+    and the compiler, not from the array types (``jax.make_mesh``
+    defaults to Explicit axes)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_fleet_mesh(n_devices: int):
@@ -44,10 +49,9 @@ def make_fleet_mesh(n_devices: int):
             f"{len(jax.devices())}; set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={n_devices} "
             f"(CPU) or use placement='host'")
-    # Auto axes: the fleet body is a manual shard_map over "fleet", and
-    # its state carries no sharding in its types
-    return jax.make_mesh((n_devices,), ("fleet",),
-                         axis_types=(jax.sharding.AxisType.Auto,))
+    # the fleet body is a manual shard_map over "fleet", and its state
+    # carries no sharding in its types
+    return make_mesh((n_devices,), ("fleet",))
 
 
 def data_axes(mesh) -> tuple[str, ...]:
