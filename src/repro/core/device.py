@@ -72,7 +72,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import compile_cache, isa, trace_engine
+from . import compile_cache, isa, trace_engine, tracing
 from .cycles import ProgramTrace, program_trace
 from .isa import NUM_CLASSES, Op
 from .packing import PACKINGS, WavePacking, pack_waves
@@ -872,298 +872,328 @@ def launch(dcfg: DeviceConfig, program=None, grid=None,
     with the default zero latencies the model is free and the profile key
     is absent — bit-identical to the pre-serving device.
     """
-    # ---- normalize to kernels + grid_map --------------------------------
-    kernels, gmap, shmems = _normalize_grid(dcfg, program, grid, block,
-                                            dim_x, programs, grid_map,
-                                            shmem)
-    n_blocks = int(gmap.shape[0])
-    bids = None
-    if block_ids is not None:
-        bids = np.asarray(list(block_ids), np.int64)
-        if bids.shape != (n_blocks,):
-            raise ValueError(f"block_ids has shape {bids.shape}, want "
-                             f"({n_blocks},)")
-        if (bids < 0).any():
-            raise ValueError("block_ids must be non-negative")
-    backend = backend or dcfg.backend
-    mode = _resolve_schedule(schedule, dcfg, len(kernels))
-    compile_cache.configure_jax_cache()
+    with tracing.span("egpu.launch"):
+        # ---- plan: normalize, lower, resolve, look the plans up ---------
+        with tracing.span("egpu.launch.plan"):
+            kernels, gmap, shmems = _normalize_grid(
+                dcfg, program, grid, block, dim_x, programs, grid_map,
+                shmem)
+            n_blocks = int(gmap.shape[0])
+            bids = None
+            if block_ids is not None:
+                bids = np.asarray(list(block_ids), np.int64)
+                if bids.shape != (n_blocks,):
+                    raise ValueError(f"block_ids has shape {bids.shape}, "
+                                     f"want ({n_blocks},)")
+                if (bids < 0).any():
+                    raise ValueError("block_ids must be non-negative")
+            backend = backend or dcfg.backend
+            mode = _resolve_schedule(schedule, dcfg, len(kernels))
+            compile_cache.configure_jax_cache()
 
-    # ---- host dispatch latency (the launch-queue model) ------------------
-    if queue_depth < 0:
-        raise ValueError(f"queue_depth={queue_depth} must be >= 0")
-    host_latency = dcfg.dispatch_latency + dcfg.queue_latency * queue_depth
-    host_dispatch = None
-    if dcfg.dispatch_latency or dcfg.queue_latency:
-        host_dispatch = {
-            "queue_depth": int(queue_depth),
-            "dispatch_cycles": int(dcfg.dispatch_latency),
-            "queue_cycles": int(dcfg.queue_latency * queue_depth),
-            "latency_cycles": int(host_latency),
-        }
+            # host dispatch latency (the launch-queue model)
+            if queue_depth < 0:
+                raise ValueError(f"queue_depth={queue_depth} must be >= 0")
+            host_latency = dcfg.dispatch_latency \
+                + dcfg.queue_latency * queue_depth
+            host_dispatch = None
+            if dcfg.dispatch_latency or dcfg.queue_latency:
+                host_dispatch = {
+                    "queue_depth": int(queue_depth),
+                    "dispatch_cycles": int(dcfg.dispatch_latency),
+                    "queue_cycles": int(dcfg.queue_latency * queue_depth),
+                    "latency_cycles": int(host_latency),
+                }
 
-    # ---- priority visibility: static waves ignore Kernel(priority=) -----
-    prioritized = any(k.priority for k in kernels)
-    priority_respected = (mode == "dynamic") or not prioritized
-    if prioritized and mode == "static":
-        _warn_static_priority()
+            # priority visibility: static waves ignore Kernel(priority=)
+            prioritized = any(k.priority for k in kernels)
+            priority_respected = (mode == "dynamic") or not prioritized
+            if prioritized and mode == "static":
+                _warn_static_priority()
 
-    # ---- per-program static resources -----------------------------------
-    names, cfgs, imems, traces, word_arrays = _lower_kernels(dcfg, kernels)
-    eng, eng_fallback = _resolve_engine(engine, dcfg, traces)
-    present = [k for k in range(len(kernels)) if (gmap == k).any()]
-    # heterogeneous grids take the MERGED path on both compiled engines:
-    # blocks of different programs share one wave, executed either as a
-    # single scan over the padded merged schedule
-    # (trace_engine.MergedTraceSchedule) or as per-slot fused segments
-    # with globally-ordered gmem rows (MergedMegakernelPlan)
-    use_merged = eng in ("trace", "megakernel") and len(present) > 1
-    # lower only the kernels that actually own blocks in this grid (the
-    # merged path lowers through the same per-program compile cache)
-    scheds = [trace_engine.compile_program(w, c)
-              if eng == "trace" and not use_merged and (gmap == k).any()
-              else None
-              for k, (w, c) in enumerate(zip(word_arrays, cfgs))]
-    plans = [trace_engine.compile_megakernel(w, c)
-             if eng == "megakernel" and not use_merged
-             and (gmap == k).any() else None
-             for k, (w, c) in enumerate(zip(word_arrays, cfgs))]
+            # per-program static resources
+            names, cfgs, imems, traces, word_arrays = _lower_kernels(
+                dcfg, kernels)
+            eng, eng_fallback = _resolve_engine(engine, dcfg, traces)
+            present = [k for k in range(len(kernels)) if (gmap == k).any()]
+            # heterogeneous grids take the MERGED path on both compiled
+            # engines: blocks of different programs share one wave,
+            # executed either as a single scan over the padded merged
+            # schedule (trace_engine.MergedTraceSchedule) or as per-slot
+            # fused segments with globally-ordered gmem rows
+            # (MergedMegakernelPlan)
+            use_merged = eng in ("trace", "megakernel") and len(present) > 1
+            # lower only the kernels that actually own blocks in this grid
+            # (the merged path lowers through the same per-program compile
+            # cache)
+            scheds = [trace_engine.compile_program(w, c)
+                      if eng == "trace" and not use_merged and k in present
+                      else None
+                      for k, (w, c) in enumerate(zip(word_arrays, cfgs))]
+            plans = [trace_engine.compile_megakernel(w, c)
+                     if eng == "megakernel" and not use_merged
+                     and k in present else None
+                     for k, (w, c) in enumerate(zip(word_arrays, cfgs))]
 
-    # ---- wave packing: one membership decision for every layer ----------
-    # the packer keys on each block's pre-decoded schedule length
-    # (``trace.data_steps`` — the scan rows a merged wave pads to, cached
-    # on the trace so repeated launches pay nothing); the SAME
-    # WavePacking then shapes the merged functional waves, the static
-    # wave timing, and the dynamic queue's dispatch order
-    phase_of_kernel = np.cumsum([int(k.barrier) for k in kernels])
-    block_phase = phase_of_kernel[gmap]
-    wp = pack_waves([traces[k].data_steps for k in gmap], dcfg.n_sms,
-                    policy=packing if packing is not None
-                    else dcfg.packing,
-                    phase_of=block_phase)
+            with tracing.span("egpu.launch.schedule"):
+                # wave packing: one membership decision for every layer.
+                # The packer keys on each block's pre-decoded schedule
+                # length (``trace.data_steps`` — the scan rows a merged
+                # wave pads to, cached on the trace so repeated launches
+                # pay nothing); the SAME WavePacking then shapes the merged
+                # functional waves, the static wave timing, and the dynamic
+                # queue's dispatch order
+                phase_of_kernel = np.cumsum([int(k.barrier)
+                                             for k in kernels])
+                block_phase = phase_of_kernel[gmap]
+                wp = pack_waves([traces[k].data_steps for k in gmap],
+                                dcfg.n_sms,
+                                policy=packing if packing is not None
+                                else dcfg.packing,
+                                phase_of=block_phase)
 
-    # ---- the schedule (timing) ------------------------------------------
-    block_priority = np.asarray([kernels[k].priority for k in gmap],
-                                np.int64)
-    block_traces = [traces[k] for k in gmap]
-    timing = schedule_blocks(block_traces, dcfg.n_sms, mode,
-                             phase_of=block_phase,
-                             priority_of=block_priority,
-                             packing=wp, start_cycle=host_latency)
-    if mode == "static":
-        static_span = timing.makespan
-    else:
-        static_span = schedule_blocks(block_traces, dcfg.n_sms, "static",
-                                      phase_of=block_phase,
-                                      packing=wp,
-                                      start_cycle=host_latency).makespan
-
-    # ---- global-memory image --------------------------------------------
-    offsets = None
-    if buffers is not None:
-        if gmem is not None:
-            raise ValueError("pass either buffers= or gmem=, not both")
-        gm, offsets = pack_buffers(buffers, dcfg.global_mem_depth)
-    elif gmem is not None:
-        gm = as_u32_image(gmem, dcfg.global_mem_depth, "global-memory")
-    else:
-        gm = jnp.zeros((dcfg.global_mem_depth,), _U32)
-
-    # ---- functional execution (exact lockstep batches) -------------------
-    regs_slots: list[Any] = [None] * n_blocks
-    shmem_slots: list[Any] = [None] * n_blocks
-    oob_slots: list[Any] = [None] * n_blocks
-    wave_cycles, wave_steps = [], []
-    machine_by = np.zeros((NUM_CLASSES,), np.int64)
-    halted = True
-    shmem_pad = dcfg.sm.shmem_depth
-    merge_stats: dict[str, Any] | None = None
-    if use_merged:
-        # Heterogeneous waves: the wave packing decides which blocks
-        # share a wave (grid order within each barrier phase under the
-        # default policy; pad-minimal membership under "length" — a
-        # merged wave never spans a fence either way) and each wave runs
-        # as ONE merged scan. Cross-program global-memory interactions
-        # inside a wave resolve in device order (per-step, program-slot
-        # then (sm, thread) drain); as on real hardware, blocks that may
-        # run concurrently must not race through global memory —
-        # Kernel(barrier=True) is the fence for cross-block dataflow,
-        # and under that contract results are bit-identical to the step
-        # machine's canonical program-major order for EVERY packing
-        # (pinned by tests/test_conformance.py).
-        local_bid = np.zeros(n_blocks, np.int64)
-        sh_batches: dict[int, Any] = {}
-        for k in present:
-            pos = np.flatnonzero(gmap == k)
-            local_bid[pos] = np.arange(pos.size)
-            sh_batches[k] = _kernel_shmem(shmems[k], cfgs[k].shmem_depth,
-                                          pos.size, k)
-        # one merged schedule per wave SIGNATURE (the programs present):
-        # memoized here so the wave loop never re-keys the word arrays;
-        # the packed membership decides which signatures (multisets of
-        # (program, SMConfig) pairs) ever get compiled
-        msched_of: dict[tuple[int, ...], Any] = {}
-
-        def merged_sched(sig):
-            if sig not in msched_of:
-                progs = [word_arrays[k] for k in sig]
-                cs = [cfgs[k] for k in sig]
-                msched_of[sig] = \
-                    trace_engine.compile_merged_megakernel(progs, cs) \
-                    if eng == "megakernel" \
-                    else trace_engine.compile_merged(progs, cs)
-            return msched_of[sig]
-
-        per_wave: list[dict[str, Any]] = []
-        for wave_ids in wp.waves:
-            wave = np.asarray(wave_ids, np.int64)
-            sig = tuple(sorted({int(gmap[b]) for b in wave}))
-            msched = merged_sched(sig)
-            slot = np.asarray([sig.index(int(gmap[b])) for b in wave])
-            # slot-major member order: each program's dispatch runs on
-            # a contiguous sub-batch (grid order kept within a slot)
-            order = np.argsort(slot, kind="stable")
-            blocks, slot = wave[order], slot[order]
-            counts = np.bincount(slot, minlength=len(sig))
-            n = blocks.size
-            pids = gmap[blocks]
-            # per-slot shared-memory init, padded to the device depth
-            # and concatenated along the slot-major member order
-            segs, off = [], 0
-            for j, k in enumerate(sig):
-                c = int(counts[j])
-                batch = sh_batches[k]
-                if batch is None:
-                    segs.append(jnp.zeros((c, shmem_pad), _U32))
+                # the schedule (timing)
+                block_priority = np.asarray(
+                    [kernels[k].priority for k in gmap], np.int64)
+                block_traces = [traces[k] for k in gmap]
+                timing = schedule_blocks(block_traces, dcfg.n_sms, mode,
+                                         phase_of=block_phase,
+                                         priority_of=block_priority,
+                                         packing=wp,
+                                         start_cycle=host_latency)
+                if mode == "static":
+                    static_span = timing.makespan
                 else:
-                    img = batch[local_bid[blocks[off:off + c]]]
-                    if img.shape[1] < shmem_pad:
-                        img = jnp.pad(
-                            img,
-                            ((0, 0), (0, shmem_pad - img.shape[1])))
-                    segs.append(img)
-                off += c
-            sh0 = jnp.concatenate(segs, axis=0)
+                    static_span = schedule_blocks(
+                        block_traces, dcfg.n_sms, "static",
+                        phase_of=block_phase, packing=wp,
+                        start_cycle=host_latency).makespan
+
+            # one merged plan per wave SIGNATURE (the programs present),
+            # looked up once per signature: the packed membership decides
+            # which signatures (multisets of (program, SMConfig) pairs)
+            # ever get compiled
+            wave_sigs, msched_of = [], {}
+            if use_merged:
+                compile_merged = trace_engine.compile_merged_megakernel \
+                    if eng == "megakernel" else trace_engine.compile_merged
+                for wave_ids in wp.waves:
+                    sig = tuple(sorted({int(gmap[b]) for b in wave_ids}))
+                    if sig not in msched_of:
+                        msched_of[sig] = compile_merged(
+                            [word_arrays[k] for k in sig],
+                            [cfgs[k] for k in sig])
+                    wave_sigs.append(sig)
+
+        # ---- stage: the global-memory image and the shmem batches -------
+        with tracing.span("egpu.launch.stage"):
+            offsets = None
+            if buffers is not None:
+                if gmem is not None:
+                    raise ValueError("pass either buffers= or gmem=, not "
+                                     "both")
+                gm, offsets = pack_buffers(buffers, dcfg.global_mem_depth)
+            elif gmem is not None:
+                gm = as_u32_image(gmem, dcfg.global_mem_depth,
+                                  "global-memory")
+            else:
+                gm = jnp.zeros((dcfg.global_mem_depth,), _U32)
+            # each present program's blocks, their program-local BIDs and
+            # its shared-memory batch
+            pos_of = {k: np.flatnonzero(gmap == k) for k in present}
+            local_bid = np.zeros(n_blocks, np.int64)
+            for k, pos in pos_of.items():
+                local_bid[pos] = np.arange(pos.size)
+            sh_batches = {k: _kernel_shmem(shmems[k], cfgs[k].shmem_depth,
+                                           pos.size, k)
+                          for k, pos in pos_of.items()}
+
+        # ---- functional execution (exact lockstep batches) ---------------
+        regs_slots: list[Any] = [None] * n_blocks
+        shmem_slots: list[Any] = [None] * n_blocks
+        oob_slots: list[Any] = [None] * n_blocks
+        wave_cycles, wave_steps = [], []
+        machine_by = np.zeros((NUM_CLASSES,), np.int64)
+        halted = True
+        shmem_pad = dcfg.sm.shmem_depth
+        merge_stats: dict[str, Any] | None = None
+        if use_merged:
+            # Heterogeneous waves: the wave packing decides which blocks
+            # share a wave (grid order within each barrier phase under the
+            # default policy; pad-minimal membership under "length" — a
+            # merged wave never spans a fence either way) and each wave
+            # runs as ONE merged scan. Cross-program global-memory
+            # interactions inside a wave resolve in device order
+            # (per-step, program-slot then (sm, thread) drain); as on real
+            # hardware, blocks that may run concurrently must not race
+            # through global memory — Kernel(barrier=True) is the fence
+            # for cross-block dataflow, and under that contract results
+            # are bit-identical to the step machine's canonical
+            # program-major order for EVERY packing (pinned by
+            # tests/test_conformance.py).
             run_merged = trace_engine.run_wave_merged_megakernel \
                 if eng == "megakernel" else trace_engine.run_wave_merged
             engine_bid = bids if bids is not None else local_bid
-            regs_f, sh_f, gm, oob_f = run_merged(
-                backend, msched, counts, engine_bid[blocks], pids,
-                jnp.zeros((n, MAX_THREADS, N_REGS), _U32), sh0, gm,
-                jnp.zeros((n,), jnp.bool_))
-            for i, b in enumerate(blocks):
-                regs_slots[b] = regs_f[i]
-                shmem_slots[b] = sh_f[i]
-                oob_slots[b] = oob_f[i]
-            halted = halted and msched.halted
-            rec = {
-                "programs": [names[k] for k in sig],
-                "width": int(n),
-                "scan_steps": int(msched.n_steps),
-            }
-            if eng == "megakernel":
-                # fused segments execute no padded rows: short members
-                # simply stop fusing earlier, so the merge's only
-                # cross-slot cost is the globally-ordered gmem drains —
-                # surfaced as per-wave fusion stats instead
-                rec.update(padded_steps=0, pad_overhead=0.0,
-                           fusion=msched.stats())
+            per_wave: list[dict[str, Any]] = []
+            for wave_ids, sig in zip(wp.waves, wave_sigs):
+                msched = msched_of[sig]
+                with tracing.span("egpu.launch.stage"):
+                    wave = np.asarray(wave_ids, np.int64)
+                    slot = np.asarray([sig.index(int(gmap[b]))
+                                       for b in wave])
+                    # slot-major member order: each program's dispatch
+                    # runs on a contiguous sub-batch (grid order kept
+                    # within a slot)
+                    order = np.argsort(slot, kind="stable")
+                    blocks, slot = wave[order], slot[order]
+                    counts = np.bincount(slot, minlength=len(sig))
+                    n = blocks.size
+                    pids = gmap[blocks]
+                    # per-slot shared-memory init, padded to the device
+                    # depth and concatenated along the slot-major member
+                    # order
+                    segs, off = [], 0
+                    for j, k in enumerate(sig):
+                        c = int(counts[j])
+                        batch = sh_batches[k]
+                        if batch is None:
+                            segs.append(jnp.zeros((c, shmem_pad), _U32))
+                        else:
+                            img = batch[local_bid[blocks[off:off + c]]]
+                            if img.shape[1] < shmem_pad:
+                                img = jnp.pad(
+                                    img,
+                                    ((0, 0), (0, shmem_pad - img.shape[1])))
+                            segs.append(img)
+                        off += c
+                    sh0 = jnp.concatenate(segs, axis=0)
+                with tracing.span("egpu.launch.dispatch"):
+                    regs_f, sh_f, gm, oob_f = run_merged(
+                        backend, msched, counts, engine_bid[blocks], pids,
+                        jnp.zeros((n, MAX_THREADS, N_REGS), _U32), sh0, gm,
+                        jnp.zeros((n,), jnp.bool_))
+                with tracing.span("egpu.launch.unpack"):
+                    for i, b in enumerate(blocks):
+                        regs_slots[b] = regs_f[i]
+                        shmem_slots[b] = sh_f[i]
+                        oob_slots[b] = oob_f[i]
+                    halted = halted and msched.halted
+                    rec = {
+                        "programs": [names[k] for k in sig],
+                        "width": int(n),
+                        "scan_steps": int(msched.n_steps),
+                    }
+                    if eng == "megakernel":
+                        # fused segments execute no padded rows: short
+                        # members simply stop fusing earlier, so the
+                        # merge's only cross-slot cost is the
+                        # globally-ordered gmem drains — surfaced as
+                        # per-wave fusion stats instead
+                        rec.update(padded_steps=0, pad_overhead=0.0,
+                                   fusion=msched.stats())
+                    else:
+                        pad = int(msched.padded_steps(slot))
+                        rows = int(msched.n_steps) * n
+                        rec.update(padded_steps=pad,
+                                   pad_overhead=(pad / rows) if rows
+                                   else 0.0)
+                    per_wave.append(rec)
+        else:
+            # homogeneous path: exact lockstep batches per program,
+            # program-major
+            for k, pos in pos_of.items():
+                cfg, (lo, hi) = cfgs[k], imems[k]
+                sh_batch = sh_batches[k]
+                for w0 in range(0, pos.size, dcfg.n_sms):
+                    w1 = min(w0 + dcfg.n_sms, pos.size)
+                    n = w1 - w0
+                    with tracing.span("egpu.launch.stage"):
+                        st = init_device_state(
+                            cfg, n, gmem_depth=dcfg.global_mem_depth,
+                            shmem=None if sh_batch is None
+                            else sh_batch[w0:w1],
+                            gmem=gm)
+                        bidx = jnp.arange(w0, w1, dtype=_I32) \
+                            if bids is None \
+                            else jnp.asarray(bids[pos[w0:w1]], _I32)
+                        pidx = jnp.full((n,), k, dtype=_I32)
+                    with tracing.span("egpu.launch.dispatch"):
+                        if eng == "trace":
+                            fin = trace_engine.run_wave_trace(
+                                cfg, backend, scheds[k], bidx, pidx, st)
+                        elif eng == "megakernel":
+                            fin = trace_engine.run_wave_megakernel(
+                                backend, plans[k], bidx, pidx, st)
+                        else:
+                            fin = run_wave(cfg, backend, lo, hi, bidx, pidx,
+                                           st)
+                    with tracing.span("egpu.launch.unpack"):
+                        gm = fin.gmem           # batches run back to back
+                        fin_shmem = fin.shmem
+                        if cfg.shmem_depth < shmem_pad:
+                            # per-Kernel shmem_depth override: pad back to
+                            # the device depth so mixed launches still
+                            # stack in LaunchResult
+                            fin_shmem = jnp.pad(
+                                fin_shmem,
+                                ((0, 0), (0, shmem_pad - cfg.shmem_depth)))
+                        for i, b in enumerate(pos[w0:w1]):
+                            regs_slots[b] = fin.regs[i]
+                            shmem_slots[b] = fin_shmem[i]
+                            oob_slots[b] = fin.oob[i]
+                        wave_cycles.append(int(fin.cycles))
+                        wave_steps.append(int(fin.steps))
+                        machine_by += np.asarray(fin.cycles_by_class,
+                                                 np.int64)
+                        halted = halted and bool(fin.halted)
+
+        # ---- unpack: aggregate counters and the result's arrays ----------
+        with tracing.span("egpu.launch.unpack"):
+            if use_merged:
+                merge_stats = trace_engine.merge_profile(per_wave, wp.policy)
+            if mode == "static" and len(kernels) == 1:
+                # the lockstep fast path: one program, shared sequencer per
+                # wave — report the batch machine's own counters
+                # (bit-identical to the first device layer; the
+                # host-dispatch charge precedes the first wave)
+                cycles = int(sum(wave_cycles)) + int(host_latency)
+                steps = int(sum(wave_steps))
+                by_class = machine_by
+                waves_out = np.asarray(wave_cycles, np.int64)
             else:
-                pad = int(msched.padded_steps(slot))
-                rows = int(msched.n_steps) * n
-                rec.update(padded_steps=pad,
-                           pad_overhead=(pad / rows) if rows else 0.0)
-            per_wave.append(rec)
-        merge_stats = trace_engine.merge_profile(per_wave, wp.policy)
-    else:
-        # homogeneous path: exact lockstep batches per program,
-        # program-major
-        for k, kern in enumerate(kernels):
-            pos = np.flatnonzero(gmap == k)
-            if pos.size == 0:
-                continue
-            cfg, (lo, hi) = cfgs[k], imems[k]
-            sh_batch = _kernel_shmem(shmems[k], cfg.shmem_depth, pos.size,
-                                     k)
-            for w0 in range(0, pos.size, dcfg.n_sms):
-                w1 = min(w0 + dcfg.n_sms, pos.size)
-                n = w1 - w0
-                st = init_device_state(
-                    cfg, n, gmem_depth=dcfg.global_mem_depth,
-                    shmem=None if sh_batch is None else sh_batch[w0:w1],
-                    gmem=gm)
-                bidx = jnp.arange(w0, w1, dtype=_I32) if bids is None \
-                    else jnp.asarray(bids[pos[w0:w1]], _I32)  # local BID
-                pidx = jnp.full((n,), k, dtype=_I32)
-                if eng == "trace":
-                    fin = trace_engine.run_wave_trace(
-                        cfg, backend, scheds[k], bidx, pidx, st)
-                elif eng == "megakernel":
-                    fin = trace_engine.run_wave_megakernel(
-                        backend, plans[k], bidx, pidx, st)
-                else:
-                    fin = run_wave(cfg, backend, lo, hi, bidx, pidx, st)
-                gm = fin.gmem               # batches run back to back
-                fin_shmem = fin.shmem
-                if cfg.shmem_depth < shmem_pad:
-                    # per-Kernel shmem_depth override: pad back to the
-                    # device depth so mixed launches still stack in
-                    # LaunchResult
-                    fin_shmem = jnp.pad(
-                        fin_shmem,
-                        ((0, 0), (0, shmem_pad - cfg.shmem_depth)))
-                for i, b in enumerate(pos[w0:w1]):
-                    regs_slots[b] = fin.regs[i]
-                    shmem_slots[b] = fin_shmem[i]
-                    oob_slots[b] = fin.oob[i]
-                wave_cycles.append(int(fin.cycles))
-                wave_steps.append(int(fin.steps))
-                machine_by += np.asarray(fin.cycles_by_class, np.int64)
-                halted = halted and bool(fin.halted)
+                # per-SM sequencers: every block issues its own trace
+                cycles = timing.makespan
+                steps = sum(t.steps for t in block_traces)
+                by_class = np.zeros((NUM_CLASSES,), np.int64)
+                for t in block_traces:
+                    by_class += np.asarray(t.cycles_by_class(), np.int64)
+                waves_out = timing.wave_cycles
 
-    # ---- aggregate counters ---------------------------------------------
-    if mode == "static" and len(kernels) == 1:
-        # the lockstep fast path: one program, shared sequencer per wave —
-        # report the batch machine's own counters (bit-identical to PR 1;
-        # the host-dispatch charge precedes the first wave)
-        cycles = int(sum(wave_cycles)) + int(host_latency)
-        steps = int(sum(wave_steps))
-        by_class = machine_by
-        waves_out = np.asarray(wave_cycles, np.int64)
-    else:
-        # per-SM sequencers: every block issues its own trace
-        cycles = timing.makespan
-        steps = sum(t.steps for t in block_traces)
-        by_class = np.zeros((NUM_CLASSES,), np.int64)
-        for t in block_traces:
-            by_class += np.asarray(t.cycles_by_class(), np.int64)
-        waves_out = timing.wave_cycles
-
-    return LaunchResult(
-        grid=(n_blocks,),
-        block=cfgs[0].n_threads if len(kernels) == 1
-        else tuple(c.n_threads for c in cfgs),
-        n_waves=len(waves_out),
-        regs=jnp.stack(regs_slots, axis=0),
-        shmem=jnp.stack(shmem_slots, axis=0),
-        gmem=gm,
-        oob=jnp.stack(oob_slots, axis=0),
-        halted=halted,
-        steps=steps,
-        cycles=cycles,
-        wave_cycles=np.asarray(waves_out, np.int64),
-        cycles_by_class=by_class.astype(np.int64),
-        buffer_offsets=offsets,
-        schedule=mode,
-        engine=eng,
-        engine_fallback=eng_fallback,
-        program_names=tuple(names),
-        grid_map=gmap,
-        timing=timing,
-        static_cycles=static_span,
-        trace_merge=merge_stats,
-        packing=wp.policy,
-        wave_packing=wp,
-        host_dispatch=host_dispatch,
-        priority_respected=priority_respected,
-    )
+            return LaunchResult(
+                grid=(n_blocks,),
+                block=cfgs[0].n_threads if len(kernels) == 1
+                else tuple(c.n_threads for c in cfgs),
+                n_waves=len(waves_out),
+                regs=jnp.stack(regs_slots, axis=0),
+                shmem=jnp.stack(shmem_slots, axis=0),
+                gmem=gm,
+                oob=jnp.stack(oob_slots, axis=0),
+                halted=halted,
+                steps=steps,
+                cycles=cycles,
+                wave_cycles=np.asarray(waves_out, np.int64),
+                cycles_by_class=by_class.astype(np.int64),
+                buffer_offsets=offsets,
+                schedule=mode,
+                engine=eng,
+                engine_fallback=eng_fallback,
+                program_names=tuple(names),
+                grid_map=gmap,
+                timing=timing,
+                static_cycles=static_span,
+                trace_merge=merge_stats,
+                packing=wp.policy,
+                wave_packing=wp,
+                host_dispatch=host_dispatch,
+                priority_respected=priority_respected,
+            )

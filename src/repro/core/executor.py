@@ -716,9 +716,13 @@ def apply_segment_residual(cfg, backend: "ExecBackend", seg: FusedSegment,
     n = regs.shape[0]
     cols = [regs[:, :, r] for r in range(regs.shape[2])]
     bid, pid = _sm_column(block_idx), _sm_column(prog_idx)
+    # a literal the compiler can see would let it fold a residual FP op
+    # on the host, keeping a denormal that the device flushes to zero
+    zero = rounding_fence(block_idx)
 
     def mat(v):
-        return jnp.broadcast_to(jnp.asarray(v)[None], (n, MAX_THREADS))
+        return jnp.broadcast_to(jnp.asarray(v)[None], (n, MAX_THREADS)) \
+            | zero
 
     for kind, row, data, consts in seg.residual:
         for r, v in consts:

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from .. import tracing
 from ..device import DeviceConfig, LaunchResult, launch
 from ..machine import SMConfig
 from .fft import bitrev_indices, fft_kernel, fft_shmem
@@ -64,45 +65,48 @@ def launch_fft_qrd(xs: np.ndarray, As: np.ndarray,
     merged trace waves padding short FFT schedules to the long QRD one
     wherever the grid shape allows pure waves.
     """
-    xs, As = np.asarray(xs), np.asarray(As)
-    batch_f, n = int(xs.shape[0]), int(xs.shape[1])
-    batch_q = int(As.shape[0])
-    if device is None:
-        device = mixed_device(n, backend=backend)
-    fft_images = np.stack([fft_shmem(xs[b], device.sm.shmem_depth)
-                           for b in range(batch_f)])
-    qrd_images = np.stack([qrd_shmem(As[b], device.sm.shmem_depth)
-                           for b in range(batch_q)])
-    if interleave:
-        grid_map: list[int] = []
-        for i in range(max(batch_f, batch_q)):
-            if i < batch_f:
-                grid_map.append(0)
-            if i < batch_q:
-                grid_map.append(1)
-    else:
-        grid_map = [0] * batch_f + [1] * batch_q
-    kernels = [fft_kernel(n), qrd_kernel()]
-    if priorities is not None:
-        import dataclasses
+    with tracing.span("egpu.launch_fft_qrd"):
+        xs, As = np.asarray(xs), np.asarray(As)
+        batch_f, n = int(xs.shape[0]), int(xs.shape[1])
+        batch_q = int(As.shape[0])
+        if device is None:
+            device = mixed_device(n, backend=backend)
+        with tracing.span("egpu.inputs"):
+            fft_images = np.stack([fft_shmem(xs[b], device.sm.shmem_depth)
+                                   for b in range(batch_f)])
+            qrd_images = np.stack([qrd_shmem(As[b], device.sm.shmem_depth)
+                                   for b in range(batch_q)])
+            if interleave:
+                grid_map: list[int] = []
+                for i in range(max(batch_f, batch_q)):
+                    if i < batch_f:
+                        grid_map.append(0)
+                    if i < batch_q:
+                        grid_map.append(1)
+            else:
+                grid_map = [0] * batch_f + [1] * batch_q
+        kernels = [fft_kernel(n), qrd_kernel()]
+        if priorities is not None:
+            import dataclasses
 
-        kernels = [dataclasses.replace(k, priority=p)
-                   for k, p in zip(kernels, priorities)]
-    res = launch(device, programs=kernels,
-                 grid_map=grid_map, shmem=[fft_images, qrd_images],
-                 backend=backend, schedule=schedule, engine=engine,
-                 packing=packing)
+            kernels = [dataclasses.replace(k, priority=p)
+                       for k, p in zip(kernels, priorities)]
+        res = launch(device, programs=kernels,
+                     grid_map=grid_map, shmem=[fft_images, qrd_images],
+                     backend=backend, schedule=schedule, engine=engine,
+                     packing=packing)
 
-    # unpack per-program results: blocks are in grid_map order; program-
-    # local order is preserved within it
-    gmap = np.asarray(res.grid_map)
-    mem = np.asarray(res.shmem_f32())
-    fmem = mem[gmap == 0]
-    out_br = fmem[:, 0:2 * n:2] + 1j * fmem[:, 1:2 * n:2]
-    X = np.empty((batch_f, n), dtype=np.complex64)
-    X[:, bitrev_indices(n)] = out_br
-    qmem = mem[gmap == 1]
-    Q = qmem[:, Q_BASE:Q_BASE + 256].reshape(batch_q, 16, 16) \
-        .transpose(0, 2, 1)
-    R = qmem[:, R_BASE:R_BASE + 256].reshape(batch_q, 16, 16)
-    return X, Q, R, res
+        # unpack per-program results: blocks are in grid_map order;
+        # program-local order is preserved within it
+        with tracing.span("egpu.readback"):
+            gmap = np.asarray(res.grid_map)
+            mem = np.asarray(res.shmem_f32())
+            fmem = mem[gmap == 0]
+            out_br = fmem[:, 0:2 * n:2] + 1j * fmem[:, 1:2 * n:2]
+            X = np.empty((batch_f, n), dtype=np.complex64)
+            X[:, bitrev_indices(n)] = out_br
+            qmem = mem[gmap == 1]
+            Q = qmem[:, Q_BASE:Q_BASE + 256].reshape(batch_q, 16, 16) \
+                .transpose(0, 2, 1)
+            R = qmem[:, R_BASE:R_BASE + 256].reshape(batch_q, 16, 16)
+        return X, Q, R, res

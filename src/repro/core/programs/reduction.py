@@ -15,8 +15,10 @@ from __future__ import annotations
 
 import numpy as np
 
+from .. import tracing
 from ..assembler import Program, assemble, auto_nop
-from ..device import DeviceConfig, Kernel, LaunchResult, launch
+from ..device import (DeviceConfig, Kernel, LaunchResult, buffer_layout,
+                      launch)
 from ..executor import run
 from ..machine import SMConfig, shmem_f32
 
@@ -132,50 +134,53 @@ def launch_reduction(x: np.ndarray, device: DeviceConfig | None = None,
     The result is the whole launch's LaunchResult, so ``profile()`` shows
     both stages' per-SM occupancy.
     """
-    x = np.asarray(x, np.float32).reshape(-1)
-    n = x.shape[0]
-    block = min(block, max(16, -(-n // 16) * 16))
-    n_blocks = max(1, -(-n // block))
-    if n_blocks * block + n_blocks + 32 >= 1 << 14:
-        # every gmem offset is a GLD/GST immediate (signed 14-bit)
-        raise ValueError(f"n={n} too large for immediate addressing "
-                         f"(padded layout must stay below {1 << 14} words)")
-    x_pad = np.zeros(n_blocks * block, np.float32)
-    x_pad[:n] = x
-    # stage-2 block must be a multiple of 16 threads; excess partials are 0
-    n2 = -(-n_blocks // 16) * 16
-    buffers = {
-        "x": x_pad,
-        "partials": np.zeros(n2, np.float32),
-        "result": np.zeros(16, np.float32),
-    }
-    from ..device import buffer_layout
-
-    layout = buffer_layout(buffers)
-    src, par, res_off = (layout[k][0] for k in ("x", "partials", "result"))
-    if device is None:
-        depth = layout["result"][0] + layout["result"][1]
-        device = DeviceConfig(global_mem_depth=max(depth, 64),
-                              sm=SMConfig(max_steps=50_000))
-    stage1 = assemble(reduction_grid_asm(block, src, par, True))
-    stage2 = assemble(reduction_grid_asm(n2, par, res_off, False))
-    if fused:
-        res = launch(
-            device,
-            programs=[Kernel(stage1, block=block, name="reduce.stage1"),
-                      Kernel(stage2, block=n2, name="reduce.stage2",
-                             barrier=True)],
-            grid_map=[0] * n_blocks + [1], buffers=buffers,
-            backend=backend, schedule=schedule)
-        total = float(np.asarray(res.buffer("result"))[0])
-        return total, res
-    s1 = launch(device, stage1, grid=(n_blocks,), block=block,
-                buffers=buffers, backend=backend, schedule=schedule)
-    s2 = launch(device, stage2, grid=(1,), block=n2, gmem=s1.gmem,
+    with tracing.span("egpu.launch_reduction"):
+        x = np.asarray(x, np.float32).reshape(-1)
+        n = x.shape[0]
+        block = min(block, max(16, -(-n // 16) * 16))
+        n_blocks = max(1, -(-n // block))
+        if n_blocks * block + n_blocks + 32 >= 1 << 14:
+            # every gmem offset is a GLD/GST immediate (signed 14-bit)
+            raise ValueError(f"n={n} too large for immediate addressing "
+                             f"(padded layout must stay below {1 << 14} "
+                             f"words)")
+        with tracing.span("egpu.inputs"):
+            x_pad = np.zeros(n_blocks * block, np.float32)
+            x_pad[:n] = x
+            # stage-2 block must be a multiple of 16 threads; excess
+            # partials are 0
+            n2 = -(-n_blocks // 16) * 16
+            buffers = {
+                "x": x_pad,
+                "partials": np.zeros(n2, np.float32),
+                "result": np.zeros(16, np.float32),
+            }
+        layout = buffer_layout(buffers)
+        src, par, res_off = (layout[k][0]
+                             for k in ("x", "partials", "result"))
+        if device is None:
+            depth = layout["result"][0] + layout["result"][1]
+            device = DeviceConfig(global_mem_depth=max(depth, 64),
+                                  sm=SMConfig(max_steps=50_000))
+        stage1 = assemble(reduction_grid_asm(block, src, par, True))
+        stage2 = assemble(reduction_grid_asm(n2, par, res_off, False))
+        if fused:
+            res = launch(
+                device,
+                programs=[Kernel(stage1, block=block, name="reduce.stage1"),
+                          Kernel(stage2, block=n2, name="reduce.stage2",
+                                 barrier=True)],
+                grid_map=[0] * n_blocks + [1], buffers=buffers,
                 backend=backend, schedule=schedule)
-    s2.buffer_offsets = layout  # stage 2 inherits the stage-1 layout
-    total = float(np.asarray(s2.buffer("result"))[0])
-    return total, s2
+        else:
+            s1 = launch(device, stage1, grid=(n_blocks,), block=block,
+                        buffers=buffers, backend=backend, schedule=schedule)
+            res = launch(device, stage2, grid=(1,), block=n2, gmem=s1.gmem,
+                         backend=backend, schedule=schedule)
+            res.buffer_offsets = layout  # stage 2 inherits stage 1's layout
+        with tracing.span("egpu.readback"):
+            total = float(np.asarray(res.buffer("result"))[0])
+        return total, res
 
 
 def run_reduction(x: np.ndarray):

@@ -64,6 +64,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import tracing
 from .cycles import ProgramTrace, program_trace
 from .executor import (
     DATA_SEL_OF_OP,
@@ -151,6 +152,13 @@ def _decode_words(words: np.ndarray) -> dict[str, np.ndarray]:
 
 @functools.lru_cache(maxsize=256)
 def _compile_cached(words_key: tuple, cfg: SMConfig) -> TraceSchedule:
+    with tracing.span("egpu.plan.lower"):
+        return _lower(words_key, cfg)
+
+
+def _lower(words_key: tuple, cfg: SMConfig) -> TraceSchedule:
+    """One program's schedule: from the on-disk cache, else lowered and
+    stored there."""
     from . import compile_cache
 
     ckey = compile_cache.key_for("lowering", words_key, cfg)
@@ -346,17 +354,18 @@ def merge_profile(per_wave: list, policy: str) -> dict:
 
 @functools.lru_cache(maxsize=256)
 def _merge_cached(keys: tuple, cfgs: tuple) -> MergedTraceSchedule:
-    parts = tuple(_compile_cached(k, c) for k, c in zip(keys, cfgs))
-    n_steps = max(p.n_steps for p in parts)
-    xs = {f: jnp.stack([jnp.pad(p.xs[f], (0, n_steps - p.n_steps))
-                        for p in parts], axis=1)
-          for f in _FIELDS}
-    bounds = sorted({p.n_steps for p in parts} | {0})
-    segments = tuple(
-        (a, b, tuple(k for k, p in enumerate(parts) if p.n_steps >= b))
-        for a, b in zip(bounds[:-1], bounds[1:]))
-    return MergedTraceSchedule(cfgs=cfgs, parts=parts, xs=xs,
-                               segments=segments)
+    with tracing.span("egpu.plan.merge"):
+        parts = tuple(_compile_cached(k, c) for k, c in zip(keys, cfgs))
+        n_steps = max(p.n_steps for p in parts)
+        xs = {f: jnp.stack([jnp.pad(p.xs[f], (0, n_steps - p.n_steps))
+                            for p in parts], axis=1)
+              for f in _FIELDS}
+        bounds = sorted({p.n_steps for p in parts} | {0})
+        segments = tuple(
+            (a, b, tuple(k for k, p in enumerate(parts) if p.n_steps >= b))
+            for a, b in zip(bounds[:-1], bounds[1:]))
+        return MergedTraceSchedule(cfgs=cfgs, parts=parts, xs=xs,
+                                   segments=segments)
 
 
 def compile_merged(programs, cfgs) -> MergedTraceSchedule:
@@ -525,23 +534,24 @@ def _partial_eval_items(items, cfg_of, depth_of) -> tuple:
     from .executor import eval_segment_rows
     from .machine import N_REGS
 
-    state: dict = {}
-    out = []
-    for kind, slot, payload in items:
-        cols = state.setdefault(
-            slot, [np.zeros(MAX_THREADS, np.uint32)] * N_REGS)
-        if kind == "fused":
-            seg, cols = eval_segment_rows(cfg_of(slot), payload, cols,
-                                          depth_of(slot))
-            state[slot] = cols
-            out.append((kind, slot, seg))
-        else:
-            if payload.sel == 8:                    # GLD: rd now runtime
-                cols = list(cols)
-                cols[int(payload.d["rd"])] = None
+    with tracing.span("egpu.plan.partial_eval"):
+        state: dict = {}
+        out = []
+        for kind, slot, payload in items:
+            cols = state.setdefault(
+                slot, [np.zeros(MAX_THREADS, np.uint32)] * N_REGS)
+            if kind == "fused":
+                seg, cols = eval_segment_rows(cfg_of(slot), payload, cols,
+                                              depth_of(slot))
                 state[slot] = cols
-            out.append((kind, slot, payload))
-    return tuple(out)
+                out.append((kind, slot, seg))
+            else:
+                if payload.sel == 8:                # GLD: rd now runtime
+                    cols = list(cols)
+                    cols[int(payload.d["rd"])] = None
+                    state[slot] = cols
+                out.append((kind, slot, payload))
+        return tuple(out)
 
 
 def _fusion_stats(items) -> dict:
@@ -601,12 +611,14 @@ def _megakernel_runner(words_key: tuple, cfg: SMConfig, backend_name: str):
     """The jitted homogeneous-wave megakernel for one (program, config,
     backend). The plan is closed over, not passed: its rows hold
     unhashable host constants, and closing over it keys XLA's jit cache
-    on exactly (plan identity, batch shapes)."""
+    on exactly (plan identity, batch shapes). The function's name names
+    the XLA module, so a profile's device operations say which runner
+    ran."""
     plan = _megakernel_cached(words_key, cfg)
     backend = get_execute_backend(backend_name)
 
     @jax.jit
-    def run(block_idx, prog_idx, regs, shmem, gmem, oob):
+    def egpu_wave_megakernel(block_idx, prog_idx, regs, shmem, gmem, oob):
         for kind, _, payload in plan.items:
             if kind == "fused":
                 regs, shmem, oob = exec_segment(
@@ -620,7 +632,7 @@ def _megakernel_runner(words_key: tuple, cfg: SMConfig, backend_name: str):
                     (regs, shmem, gmem, oob))
         return regs, shmem, gmem, oob
 
-    return run
+    return egpu_wave_megakernel
 
 
 def run_wave_megakernel(backend: str, plan: MegakernelPlan, block_idx,
@@ -679,28 +691,29 @@ class MergedMegakernelPlan:
 @functools.lru_cache(maxsize=256)
 def _merged_megakernel_cached(keys: tuple, cfgs: tuple
                               ) -> MergedMegakernelPlan:
-    parts = tuple(_compile_cached(k, c) for k, c in zip(keys, cfgs))
-    slot_rows = [_fused_rows(p) for p in parts]
-    # global-port rows must drain in the merged scan's dispatch order:
-    # (schedule step, slot order) — between them, different slots' rows
-    # touch disjoint per-SM state and commute, so each slot's runs fuse
-    # independently and flush only when one of its gmem rows comes due
-    events = sorted((i, k) for k, rows in enumerate(slot_rows)
-                    for i, r in enumerate(rows) if r.sel in _GMEM_SELS)
-    cursor = [0] * len(parts)
-    items = []
-    for i, k in events:
-        if cursor[k] < i:
-            items.append(("fused", k, tuple(slot_rows[k][cursor[k]:i])))
-        items.append(("gmem", k, slot_rows[k][i]))
-        cursor[k] = i + 1
-    for k, rows in enumerate(slot_rows):
-        if cursor[k] < len(rows):
-            items.append(("fused", k, tuple(rows[cursor[k]:])))
-    items = _partial_eval_items(
-        tuple(items), lambda s: cfgs[s], lambda s: cfgs[s].shmem_depth)
-    return MergedMegakernelPlan(keys=keys, cfgs=cfgs, parts=parts,
-                                items=items)
+    with tracing.span("egpu.plan.merge"):
+        parts = tuple(_compile_cached(k, c) for k, c in zip(keys, cfgs))
+        slot_rows = [_fused_rows(p) for p in parts]
+        # global-port rows must drain in the merged scan's dispatch order:
+        # (schedule step, slot order) — between them, different slots' rows
+        # touch disjoint per-SM state and commute, so each slot's runs fuse
+        # independently and flush only when one of its gmem rows comes due
+        events = sorted((i, k) for k, rows in enumerate(slot_rows)
+                        for i, r in enumerate(rows) if r.sel in _GMEM_SELS)
+        cursor = [0] * len(parts)
+        items = []
+        for i, k in events:
+            if cursor[k] < i:
+                items.append(("fused", k, tuple(slot_rows[k][cursor[k]:i])))
+            items.append(("gmem", k, slot_rows[k][i]))
+            cursor[k] = i + 1
+        for k, rows in enumerate(slot_rows):
+            if cursor[k] < len(rows):
+                items.append(("fused", k, tuple(rows[cursor[k]:])))
+        items = _partial_eval_items(
+            tuple(items), lambda s: cfgs[s], lambda s: cfgs[s].shmem_depth)
+        return MergedMegakernelPlan(keys=keys, cfgs=cfgs, parts=parts,
+                                    items=items)
 
 
 def compile_merged_megakernel(programs, cfgs) -> MergedMegakernelPlan:
@@ -720,7 +733,8 @@ def _merged_megakernel_runner(keys: tuple, cfgs: tuple,
     backend = get_execute_backend(backend_name)
 
     @functools.partial(jax.jit, static_argnums=(0,))
-    def run(counts, block_idx, prog_idx, regs, shmem, gmem, oob):
+    def egpu_wave_merged_megakernel(counts, block_idx, prog_idx, regs, shmem,
+                                    gmem, oob):
         offs = np.concatenate([[0], np.cumsum(counts)])
         for kind, k, payload in mplan.items:
             cfg = cfgs[k]
@@ -742,7 +756,7 @@ def _merged_megakernel_runner(keys: tuple, cfgs: tuple,
             oob = oob.at[lo:hi].set(o_k)
         return regs, shmem, gmem, oob
 
-    return run
+    return egpu_wave_merged_megakernel
 
 
 def run_wave_merged_megakernel(backend: str, mplan: MergedMegakernelPlan,

@@ -58,6 +58,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import tracing
 from ..core.device import DeviceConfig, Kernel, as_kernel, launch
 from ..core.machine import MAX_THREADS, N_REGS
 
@@ -214,6 +215,10 @@ class LaunchServer:
         was blocked in the full-queue wait while ``stop()`` ran — it
         must not enqueue into a dead server and hang its client.
         """
+        with tracing.span("egpu.serve.submit"):
+            return self._submit(req)
+
+    def _submit(self, req: LaunchRequest) -> Future:
         with self._lock:
             if self._stopping:
                 return self._unadmitted_future_locked(req)
@@ -327,7 +332,8 @@ class LaunchServer:
         ids = {id(e) for e in batch}
         self._queue = [e for e in self._queue if id(e) not in ids]
         try:
-            self._dispatch_batch(batch, now, depth)
+            with tracing.span("egpu.serve.batch"):
+                self._dispatch_batch(batch, now, depth)
         except Exception as exc:        # route the failure to the clients
             for e in batch:
                 e.future.set_exception(exc)
@@ -338,7 +344,19 @@ class LaunchServer:
 
     def _dispatch_batch(self, batch: list[_Entry], now: int,
                         depth: int) -> None:
-        # ---- build one merged launch: dedup kernels, request-major grid --
+        with tracing.span("egpu.serve.assemble"):
+            kernels, gmap, shmems, blocks_of = self._assemble(batch)
+        solo = batch[0].req.buffers if len(batch) == 1 else None
+        res = launch(self.dcfg, programs=kernels, grid_map=gmap,
+                     shmem=shmems, buffers=solo, queue_depth=depth,
+                     **self._launch_kw)
+        with tracing.span("egpu.serve.scatter"):
+            self._scatter(batch, res, blocks_of, solo, now, depth)
+
+    def _assemble(self, batch: list[_Entry]):
+        """One merged launch of the batch: deduplicated kernels, the
+        request-major grid map, each kernel's shared-memory batch, and
+        each request's blocks."""
         kernels: list[Kernel] = []
         kernel_of: dict[tuple, int] = {}
         blocks_of: list[list[int]] = [[] for _ in batch]
@@ -382,13 +400,12 @@ class LaunchServer:
             parts = [np.pad(p, ((0, 0), (0, width - p.shape[1])))
                      if p.shape[1] < width else p for p in parts]
             shmems.append(np.concatenate(parts, axis=0))
-        solo = batch[0].req.buffers if len(batch) == 1 else None
+        return kernels, gmap, shmems, blocks_of
 
-        res = launch(self.dcfg, programs=kernels, grid_map=gmap,
-                     shmem=shmems, buffers=solo, queue_depth=depth,
-                     **self._launch_kw)
-
-        # ---- route per-request slices + cycle counts back ----------------
+    def _scatter(self, batch: list[_Entry], res, blocks_of, solo, now: int,
+                 depth: int) -> None:
+        """Route per-request slices and cycle counts back to the futures,
+        and advance the virtual clock."""
         finish = np.asarray(res.timing.block_finish)
         bid = self._batch_id
         self._batch_id += 1

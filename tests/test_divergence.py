@@ -354,6 +354,32 @@ def test_fuzz_predicated_programs_conform(prog, seed, n_sms, schedule,
     assert_bit_identical(outs["step"], outs["megakernel"])
 
 
+def test_megakernel_flushes_a_denormal_sum_of_known_values():
+    """A guarded FP add of plan-time constants runs in the fused segment
+    as a residual op; it must flush its denormal result to zero like the
+    device does, not keep it as a compile-time fold would (a case the
+    fuzz above found)."""
+    prog = np.array([Instr(op=Op.LODI, rd=14, imm=1).encode(),
+                     Instr(op=Op.ADD, rd=0, ra=0, rb=14).encode(),
+                     Instr(op=Op.INIT, imm=1).encode(),
+                     Instr(op=Op.ADD, width=Width.HALF).encode(),
+                     Instr(op=Op.ADD, typ=Typ.FP32, pen=1).encode(),
+                     Instr(op=Op.LOOP, imm=3).encode(),
+                     Instr(op=Op.STOP).encode()], np.int64)
+    rng = np.random.default_rng(0)
+    gmem = rng.standard_normal(64).astype(np.float32)
+    shmem = rng.standard_normal((2, 64)).astype(np.float32)
+    outs = {}
+    for engine in ("step", "megakernel"):
+        dcfg = DeviceConfig(n_sms=1, global_mem_depth=64, engine=engine,
+                            sm=SMConfig(shmem_depth=64, max_steps=500))
+        outs[engine] = launch(dcfg, prog, grid=2, block=16, gmem=gmem,
+                              shmem=shmem, schedule="static")
+    # lanes 8-15 hold 1 (a denormal's bits) and add it to itself
+    assert np.asarray(outs["step"].regs)[0, 8:16, 0].tolist() == [0] * 8
+    assert_bit_identical(outs["step"], outs["megakernel"])
+
+
 @st.composite
 def _guarded_program(draw):
     """Every body instr guarded by R15 (all-zero): (guarded, nop_swapped,
