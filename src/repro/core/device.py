@@ -625,6 +625,36 @@ def _kernel_shmem(sh: Any, depth: int, count: int, k: int):
     return batch
 
 
+@functools.partial(jax.jit, static_argnames=("shmem_depth",))
+def _gather_waves(waves, inv, *, shmem_depth: int):
+    """Concatenate each field of the per-wave ``(regs, shmem, oob)`` along
+    the block axis (shmem padded to ``shmem_depth``), then gather rows into
+    grid order by ``inv`` (None: already in grid order). ``inv`` is traced,
+    so grids whose waves have the same widths share one compile."""
+    regs = jnp.concatenate([r for r, _, _ in waves], axis=0)
+    shmem = jnp.concatenate(
+        [jnp.pad(s, ((0, 0), (0, shmem_depth - s.shape[1])))
+         for _, s, _ in waves], axis=0)
+    oob = jnp.concatenate([o for _, _, o in waves], axis=0)
+    if inv is not None:
+        regs, shmem, oob = (jnp.take(x, inv, axis=0, mode="clip")
+                            for x in (regs, shmem, oob))
+    return regs, shmem, oob
+
+
+def _assemble_blocks(waves, shmem_depth: int):
+    """``LaunchResult``'s ``regs``, ``shmem`` and ``oob`` in grid order, in
+    one device call. ``waves`` holds one ``(blocks, regs, shmem, oob)`` per
+    wave in run order: ``blocks[i]`` is the grid index of the wave's row
+    ``i``, and the waves' blocks together cover the grid once."""
+    order = np.concatenate([blocks for blocks, *_ in waves])
+    inv = None
+    if not np.array_equal(order, np.arange(order.size)):
+        inv = np.argsort(order).astype(np.int32)
+    return _gather_waves([tuple(w[1:]) for w in waves], inv,
+                         shmem_depth=shmem_depth)
+
+
 def _normalize_grid(dcfg: DeviceConfig, program, grid, block, dim_x,
                     programs, grid_map, shmem
                     ) -> tuple[list[Kernel], np.ndarray, list[Any]]:
@@ -1009,9 +1039,9 @@ def launch(dcfg: DeviceConfig, program=None, grid=None,
                           for k, pos in pos_of.items()}
 
         # ---- functional execution (exact lockstep batches) ---------------
-        regs_slots: list[Any] = [None] * n_blocks
-        shmem_slots: list[Any] = [None] * n_blocks
-        oob_slots: list[Any] = [None] * n_blocks
+        # each wave's (grid indices of its rows, regs, shmem, oob), in run
+        # order; put into grid order once, after the last wave
+        wave_outs: list[tuple[Any, ...]] = []
         wave_cycles, wave_steps = [], []
         machine_by = np.zeros((NUM_CLASSES,), np.int64)
         halted = True
@@ -1073,10 +1103,7 @@ def launch(dcfg: DeviceConfig, program=None, grid=None,
                         jnp.zeros((n, MAX_THREADS, N_REGS), _U32), sh0, gm,
                         jnp.zeros((n,), jnp.bool_))
                 with tracing.span("egpu.launch.unpack"):
-                    for i, b in enumerate(blocks):
-                        regs_slots[b] = regs_f[i]
-                        shmem_slots[b] = sh_f[i]
-                        oob_slots[b] = oob_f[i]
+                    wave_outs.append((blocks, regs_f, sh_f, oob_f))
                     halted = halted and msched.halted
                     rec = {
                         "programs": [names[k] for k in sig],
@@ -1129,18 +1156,10 @@ def launch(dcfg: DeviceConfig, program=None, grid=None,
                                            st)
                     with tracing.span("egpu.launch.unpack"):
                         gm = fin.gmem           # batches run back to back
-                        fin_shmem = fin.shmem
-                        if cfg.shmem_depth < shmem_pad:
-                            # per-Kernel shmem_depth override: pad back to
-                            # the device depth so mixed launches still
-                            # stack in LaunchResult
-                            fin_shmem = jnp.pad(
-                                fin_shmem,
-                                ((0, 0), (0, shmem_pad - cfg.shmem_depth)))
-                        for i, b in enumerate(pos[w0:w1]):
-                            regs_slots[b] = fin.regs[i]
-                            shmem_slots[b] = fin_shmem[i]
-                            oob_slots[b] = fin.oob[i]
+                        # a per-Kernel shmem_depth override is padded back
+                        # to the device depth in _assemble_blocks
+                        wave_outs.append((pos[w0:w1], fin.regs, fin.shmem,
+                                          fin.oob))
                         wave_cycles.append(int(fin.cycles))
                         wave_steps.append(int(fin.steps))
                         machine_by += np.asarray(fin.cycles_by_class,
@@ -1169,15 +1188,16 @@ def launch(dcfg: DeviceConfig, program=None, grid=None,
                     by_class += np.asarray(t.cycles_by_class(), np.int64)
                 waves_out = timing.wave_cycles
 
+            regs, shmem_out, oob = _assemble_blocks(wave_outs, shmem_pad)
             return LaunchResult(
                 grid=(n_blocks,),
                 block=cfgs[0].n_threads if len(kernels) == 1
                 else tuple(c.n_threads for c in cfgs),
                 n_waves=len(waves_out),
-                regs=jnp.stack(regs_slots, axis=0),
-                shmem=jnp.stack(shmem_slots, axis=0),
+                regs=regs,
+                shmem=shmem_out,
                 gmem=gm,
-                oob=jnp.stack(oob_slots, axis=0),
+                oob=oob,
                 halted=halted,
                 steps=steps,
                 cycles=cycles,
