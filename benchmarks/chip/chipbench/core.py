@@ -6,7 +6,9 @@ into the system under test, the comparison and the control) and its traffic
 kind (``traffic/<kind>.py``: the generator that drives the entry through
 the measured window). Each metric is read by ``metrics/<metric>.py`` from
 the run's ``Record``. Adding a configuration, a cell or a metric therefore
-adds files and entries in ``BENCHMARK.json`` and edits none.
+adds files and entries in ``BENCHMARK.json`` and edits none. A
+configuration of one device builds through ``device_config``; one with a
+``fleet`` block, for a cell on four chips, through ``fleet_config``.
 """
 from __future__ import annotations
 
@@ -88,6 +90,22 @@ def device_config(config: dict):
                          f"simulator has {have}")
     dev = dict(config["device"])
     return DeviceConfig(sm=SMConfig(**dev.pop("sm")), **dev)
+
+
+FLEET_KEYS = {"n_devices", "route", "placement", "remote_gmem_latency"}
+
+
+def fleet_config(config: dict):
+    """The configuration's ``fleet`` block as a ``FleetConfig`` of
+    ``n_devices`` copies of ``device_config(config)``; the block states
+    exactly ``FLEET_KEYS``."""
+    from repro.core import FleetConfig
+
+    block = config["fleet"]
+    if set(block) != FLEET_KEYS:
+        raise ValueError(f"fleet block states {sorted(block)}, not "
+                         f"{sorted(FLEET_KEYS)}")
+    return FleetConfig(device=device_config(config), **block)
 
 
 def cell_metrics(spec: dict, cell: str, trace: bool) -> list[dict]:

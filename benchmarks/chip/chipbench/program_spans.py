@@ -6,9 +6,14 @@ boundary inside it a child. A traced window is a closed loop of
 ``rec.attempted`` calls, each inside one ``bench.launch`` span of the
 profiler's trace, and nothing calls the program between that window and
 the readers; so the program's last ``rec.attempted`` roots are the
-window's calls, in order. Each call's time is split into the phases below
-by self time (a span's duration less its children's), and ``other`` is
-the rest of its ``bench.launch`` span: time that no phase names.
+window's calls, in order. Each call's time is split into the launch
+phases below by self time (a span's duration less its children's), and
+``other`` is the rest of its ``bench.launch`` span: time that no launch
+phase names.
+
+A reader of another span family (a fleet's, say) takes its names to
+``median_ms_of`` and leaves ``PHASES`` as it is: it reports that family's
+own time, which stays inside ``other``.
 
 A program that keeps no spans, or a window whose roots and calls do not
 pair up one for one, reads nothing.
@@ -31,10 +36,14 @@ PHASES = {
 CALL_SPAN = "bench.launch"
 
 
+def _matches(name: str, names) -> bool:
+    return any(name == n or (n.endswith(".") and name.startswith(n))
+               for n in names)
+
+
 def phase_of(name: str) -> str | None:
     for phase, names in PHASES.items():
-        if any(name == n or (n.endswith(".") and name.startswith(n))
-               for n in names):
+        if _matches(name, names):
             return phase
     return None
 
@@ -48,11 +57,11 @@ def _tracing():
     return tracing
 
 
-def calls(rec) -> list[dict[str, float]] | None:
-    """Per call of the traced window, in order: each phase's summed self
-    time in ms, and ``other``; None where the window's calls and the
-    program's roots do not pair up one for one, or a root outlasts the
-    ``bench.launch`` span it pairs with."""
+def _paired(rec) -> list[tuple[float, list[tuple[str, float]]]] | None:
+    """Per call of the traced window, in order: its ``bench.launch`` span
+    in ms, and each of its spans' name and self time in ms; None where the
+    window's calls and the program's roots do not pair up one for one, or
+    a root outlasts the ``bench.launch`` span it pairs with."""
     tracing = _tracing()
     if tracing is None or rec.trace is None or rec.attempted < 1:
         return None
@@ -74,25 +83,55 @@ def calls(rec) -> list[dict[str, float]] | None:
             tree[sp.root].append(sp)
             if sp.parent is not None:
                 child_ns[sp.parent] += sp.end_ns - sp.start_ns
+    return [((e - s) / 1e6,
+             [(sp.name, (sp.end_ns - sp.start_ns - child_ns[sp.id]) / 1e6)
+              for sp in tree[r.id]])
+            for r, (s, e) in zip(roots, launches)]
+
+
+def calls(rec) -> list[dict[str, float]] | None:
+    """Per call of the traced window, in order: each phase's summed self
+    time in ms, and ``other``; None where ``_paired`` finds no pairing."""
+    per = _paired(rec)
+    if per is None:
+        return None
     out = []
-    for r, (s, e) in zip(roots, launches):
+    for launch_ms, selfs in per:
         ms = dict.fromkeys(PHASES, 0.0)
-        for sp in tree[r.id]:
-            phase = phase_of(sp.name)
+        for name, self_ms in selfs:
+            phase = phase_of(name)
             if phase is not None:
-                ms[phase] += (sp.end_ns - sp.start_ns
-                              - child_ns[sp.id]) / 1e6
-        ms["other"] = (e - s) / 1e6 - sum(ms.values())
+                ms[phase] += self_ms
+        ms["other"] = launch_ms - sum(ms.values())
         out.append(ms)
     return out
 
 
 def median_ms(rec, phase: str) -> float | None:
-    """The median over the window's calls of ``phase``'s milliseconds."""
+    """The median over the window's calls of ``phase``'s milliseconds
+    (a phase of ``PHASES``, or ``other``)."""
+    if phase != "other":
+        return median_ms_of(rec, PHASES[phase])
     per = calls(rec)
     if per is None:
         return None
-    return statistics.median(c[phase] for c in per)
+    return statistics.median(c["other"] for c in per)
+
+
+def median_ms_of(rec, names) -> float | None:
+    """The median over the window's calls of the self time, in ms, summed
+    over the call's spans whose names match ``names`` (a name ending in
+    "." takes every span under that prefix); None where the calls do not
+    pair up, or no span of the window matches."""
+    per = _paired(rec)
+    if per is None:
+        return None
+    sums, seen = [], False
+    for _launch_ms, selfs in per:
+        got = [ms for name, ms in selfs if _matches(name, names)]
+        seen = seen or bool(got)
+        sums.append(sum(got, 0.0))
+    return statistics.median(sums) if seen else None
 
 
 def total_s(prefix: str) -> float | None:

@@ -17,6 +17,7 @@ sys.path.insert(0, str(BENCH))
 sys.path.insert(0, str(ROOT / "src"))
 
 from chipbench import core  # noqa: E402
+from repro.core import FleetConfig  # noqa: E402
 
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
 CELLS = [w["name"] for w in SPEC["workloads"]]
@@ -45,17 +46,23 @@ def test_names_are_unique_and_well_formed():
         assert NAME.match(n), n
 
 
-@pytest.mark.parametrize("cell", WORKLOAD_FILES)
-def test_cell_files_agree(cell):
-    """Every workload file, listed in ``BENCHMARK.json`` or kept for a
-    later one, names files that exist; a listed one agrees with its
-    entry."""
-    workload, config = core.cell_files(cell)
+def check_cell(cell: str, workload: dict, config: dict, spec: dict) -> None:
+    """A workload file and its configuration agree with each other, with
+    the files they name and, where ``spec`` lists the cell, with its
+    entry. A cell takes 1 or 4 chips; a four-chip cell's configuration
+    has a ``fleet`` block of as many devices, a one-chip cell's has
+    none."""
     assert workload["config"] == config["name"]
-    assert workload["chips"] == 1 and len(workload["why"]) <= 200
+    assert len(workload["why"]) <= 200
+    assert workload["chips"] in (1, 4)
+    if workload["chips"] == 4:
+        assert config["fleet"]["n_devices"] == workload["chips"]
+    else:
+        assert "fleet" not in config
     assert cell == f"{workload['config']}.{cell.split('.', 1)[1]}"
-    if cell in CELLS:
-        entry = next(w for w in SPEC["workloads"] if w["name"] == cell)
+    listed = [w for w in spec["workloads"] if w["name"] == cell]
+    if listed:
+        (entry,) = listed
         assert workload["config"] == entry["config"]
         assert workload["chips"] == entry["chips"]
         assert workload["why"] == entry["why"]
@@ -76,17 +83,109 @@ def test_cell_files_agree(cell):
     assert all(workload["limits"][k] == 0 for k in pinned)
 
 
+def check_share(spec: dict) -> None:
+    """At most half of the listed cells, rounded down, take four chips;
+    one always may."""
+    cells = spec["workloads"]
+    four = sum(w["chips"] == 4 for w in cells)
+    assert four <= max(1, len(cells) // 2), (four, len(cells))
+
+
+def check_config(name: str, config: dict, spec: dict) -> None:
+    """A configuration builds; one with a ``fleet`` block builds through
+    ``fleet_config`` and states the ``shard_map`` placement (a launch
+    that cannot take it raises, where ``auto`` would fall back to the
+    host loop) and the ``block`` route."""
+    assert config["name"] == name and config["reduced"] == []
+    assert core.device_config(config).n_sms == 4
+    if "fleet" in config:
+        fleet = core.fleet_config(config)
+        assert fleet.placement == "shard_map" and fleet.route == "block"
+    listed = [c for c in spec["configs"] if c["name"] == name]
+    if listed:
+        assert ROOT / listed[0]["file"] == BENCH / "configs" / f"{name}.json"
+        assert listed[0]["reduced"] == []
+        assert name in {w["config"] for w in spec["workloads"]}
+
+
+@pytest.mark.parametrize("cell", WORKLOAD_FILES)
+def test_cell_files_agree(cell):
+    """Every workload file, listed in ``BENCHMARK.json`` or kept for a
+    later one, names files that exist; a listed one agrees with its
+    entry."""
+    workload, config = core.cell_files(cell)
+    check_cell(cell, workload, config, SPEC)
+
+
+def test_four_chip_share():
+    check_share(SPEC)
+
+
 @pytest.mark.parametrize("name", CONFIG_FILES)
 def test_config_files(name):
     path = BENCH / "configs" / f"{name}.json"
-    data = json.loads(path.read_text())
-    assert data["name"] == name and data["reduced"] == []
-    assert core.device_config(data).n_sms == 4
-    listed = [c for c in SPEC["configs"] if c["name"] == name]
-    if listed:
-        assert ROOT / listed[0]["file"] == path
-        assert listed[0]["reduced"] == []
-        assert name in {w["config"] for w in SPEC["workloads"]}
+    check_config(name, json.loads(path.read_text()), SPEC)
+
+
+def _fleet_example(n_devices: int | None = 4, chips: int = 4):
+    """An in-test fleet cell of ``chips`` chips, its configuration with a
+    ``fleet`` block of ``n_devices`` (none where that is None), and a
+    spec that lists it after the benchmark's own cells."""
+    config = json.loads((BENCH / "configs" / "sector4.json").read_text())
+    config["name"] = "fleet4"
+    if n_devices is not None:
+        config["fleet"] = {"n_devices": n_devices, "route": "block",
+                           "placement": "shard_map",
+                           "remote_gmem_latency": 0}
+    workload = core.cell_files("sector4.fft256_qrd16_batch")[0]
+    workload.update(config="fleet4", chips=chips,
+                    why="four sector4 devices, one per chip")
+    cell = "fleet4.fft256_qrd16_batch"
+    spec = dict(SPEC, workloads=SPEC["workloads"] + [{
+        "name": cell, "config": "fleet4", "traffic": "fft256_qrd16_batch",
+        "chips": chips, "why": workload["why"]}])
+    return cell, workload, config, spec
+
+
+def test_layout_takes_a_four_chip_fleet_cell():
+    cell, workload, config, spec = _fleet_example()
+    check_cell(cell, workload, config, spec)
+    check_config("fleet4", config, spec)
+    check_share(spec)
+    check_share({"workloads": [spec["workloads"][-1]]})     # one always may
+
+
+@pytest.mark.parametrize("case", ["two_chips", "n_devices_not_chips",
+                                  "one_four_chip_cell_too_many",
+                                  "fleet_on_one_chip"])
+def test_layout_refuses(case):
+    if case == "one_four_chip_cell_too_many":
+        # one of three cells may take four chips; this spec asks two
+        spec = _fleet_example()[3]
+        spec["workloads"] = [dict(w, chips=4) if w["name"] == CELLS[0]
+                             else w for w in spec["workloads"]]
+        with pytest.raises(AssertionError):
+            check_share(spec)
+        return
+    cell, workload, config, spec = _fleet_example(**{
+        "two_chips": {"n_devices": None, "chips": 2},
+        "n_devices_not_chips": {"n_devices": 2},
+        "fleet_on_one_chip": {"chips": 1}}[case])
+    with pytest.raises(AssertionError):
+        check_cell(cell, workload, config, spec)
+
+
+def test_fleet_config_builds_from_the_block():
+    config = _fleet_example()[2]
+    config["fleet"]["remote_gmem_latency"] = 7
+    fleet = core.fleet_config(config)
+    assert isinstance(fleet, FleetConfig)
+    assert fleet.device == core.device_config(config)
+    assert (fleet.n_devices, fleet.route, fleet.placement,
+            fleet.remote_gmem_latency) == (4, "block", "shard_map", 7)
+    del config["fleet"]["remote_gmem_latency"]
+    with pytest.raises(ValueError, match="fleet block"):
+        core.fleet_config(config)
 
 
 @pytest.mark.parametrize("name", METRIC_FILES)

@@ -3,6 +3,7 @@ the ``launch.*`` and ``setup.*`` metrics) on a hand-made traced window and
 recorded spans, and each case in which they read nothing."""
 from __future__ import annotations
 
+import statistics
 import sys
 from pathlib import Path
 
@@ -97,6 +98,37 @@ def test_launch_readers_take_the_median(recorded):
     assert got == pytest.approx(want)
     # the phases and the rest make up the median call
     assert sum(got.values()) == pytest.approx(31.0)
+
+
+@pytest.mark.parametrize("phase", list(program_spans.PHASES))
+def test_median_ms_of_a_phase_reads_as_its_calls(recorded, phase):
+    """``median_ms_of`` over a phase's names gives the median of that
+    phase in ``calls``, the split the ``launch.*`` readers took before it
+    existed, to the bit."""
+    got = program_spans.median_ms_of(recorded, program_spans.PHASES[phase])
+    per = program_spans.calls(recorded)
+    assert got == statistics.median(c[phase] for c in per)
+    assert got == program_spans.median_ms(recorded, phase)
+
+
+def test_median_ms_of_reads_a_new_span_family(monkeypatch):
+    """A span family that ``PHASES`` does not name is read by its own
+    names, and its time stays inside ``other``."""
+    rec, spans = _window()
+    monkeypatch.setattr(tracing, "recent", lambda: list(spans))
+    before = program_spans.calls(rec)
+    # each call's egpu.launch ends with 1 ms x scale of a fleet's merge
+    merges = [Span("egpu.fleet.merge", sp.end_ns - (sp.end_ns - sp.start_ns)
+                   // 13, sp.end_ns, sp.id, sp.root, sp.id + 100)
+              for sp in spans if sp.name == "egpu.launch"]
+    spans += merges
+    assert program_spans.phase_of("egpu.fleet.merge") is None
+    assert program_spans.median_ms_of(rec, ("egpu.fleet.merge",)) == 1.5
+    assert program_spans.median_ms_of(rec, ("egpu.fleet.",)) == 1.5
+    assert program_spans.median_ms_of(rec, ("egpu.fleet.route",)) is None
+    assert program_spans.calls(rec) == before
+    rec.trace = None
+    assert program_spans.median_ms_of(rec, ("egpu.fleet.",)) is None
 
 
 def test_setup_readers_sum_the_totals(monkeypatch):
