@@ -1,0 +1,9 @@
+"""fleet.merge_ms: per call of the traced window, the median of the program's
+self time in merging the devices' global-memory replicas into one image
+(``egpu.fleet.merge``, inside each ``egpu.launch_fleet``;
+``chipbench.program_spans``)."""
+from chipbench.program_spans import median_ms_of
+
+
+def read(rec):
+    return median_ms_of(rec, ("egpu.fleet.merge",))

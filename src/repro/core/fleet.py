@@ -39,7 +39,9 @@ Contracts, in order of importance:
   (``launch.mesh.make_fleet_mesh`` + ``launch.shardings.fleet_spec``):
   every simulated eGPU executes its block slice on its own XLA device
   against its own gmem replica, and the replicas diff-merge exactly like
-  the host path. ``placement="auto"`` (default) uses it when it can and
+  the host path. The mapped program is jitted once per launch shape
+  (``_fleet_program``), and each device's inputs go from the host
+  straight onto that device. ``placement="auto"`` (default) uses it when it can and
   records why not when it can't (``profile()["fleet"]["placement"]`` /
   ``["placement_reason"]``); ``"host"`` forces the per-device host loop;
   ``"shard_map"`` raises when the preconditions fail instead of
@@ -60,8 +62,11 @@ from typing import Any, Mapping, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from . import compile_cache, trace_engine
+from ..launch.mesh import make_fleet_mesh
+from ..launch.shardings import fleet_spec
+from . import compile_cache, trace_engine, tracing
 from .cycles import ProgramTrace
 from .device import (
     _U32,
@@ -190,65 +195,102 @@ def _resolve_placement(fcfg: FleetConfig, kernels, gmap, block_phase,
     return "host", reason
 
 
-def _run_shard_map(fcfg: FleetConfig, backend: str, cfg, words,
-                   gmap, local_bid, device_of, sh_batch, gm):
-    """The real-JAX-devices path: one ``shard_map`` over the "fleet"
-    mesh axis; each simulated eGPU runs its contiguous block slice on
-    its own XLA device, waves of ``n_sms`` back to back against its own
-    gmem replica. Returns device-major stacked
-    ``(order, regs, shmem, gmems, oob, halted, devices)``, ``devices``
-    naming the JAX device that ran each simulated device."""
-    from ..launch.mesh import make_fleet_mesh
-    from ..launch.shardings import fleet_spec
+# one jitted shard_map program per launch shape (see _fleet_program)
+_FLEET_PROGRAMS: dict[tuple, tuple[Any, NamedSharding, NamedSharding]] = {}
+# each schedule's columns, replicated once per mesh: (id, sharding) ->
+# (schedule, placed columns)
+_PLACED_XS: dict[tuple[int, NamedSharding], tuple[Any, Any]] = {}
 
-    n_dev = fcfg.n_devices
-    n_sms = fcfg.device.n_sms
-    n_blocks = gmap.shape[0]
-    per = n_blocks // n_dev
-    sched = trace_engine.compile_program(words, cfg)
-    # route="block" on a single phase is contiguous by construction
-    order = np.concatenate([np.flatnonzero(device_of == d)
-                            for d in range(n_dev)])
-    bid = jnp.asarray(local_bid[order], _I32).reshape(n_dev, per)
-    pid = jnp.zeros((n_dev, per), _I32)
-    if sh_batch is None:
-        sh0 = jnp.zeros((n_dev, per, cfg.shmem_depth), _U32)
-    else:
-        sh0 = jnp.asarray(sh_batch)[local_bid[order]] \
-            .reshape(n_dev, per, -1)
-    regs0 = jnp.zeros((n_dev, per, MAX_THREADS, N_REGS), _U32)
-    oob0 = jnp.zeros((n_dev, per), jnp.bool_)
-    gm0 = jnp.broadcast_to(gm, (n_dev,) + gm.shape)
 
+def _fleet_program(n_dev: int, per: int, n_sms: int, cfg, backend: str,
+                   n_steps: int):
+    """``(program, sharded, replicated)``: the jitted ``shard_map`` that
+    runs ``per`` blocks on each of ``n_dev`` devices, waves of ``n_sms``
+    back to back against the device's own gmem replica, and the input
+    shardings it takes. Built once per launch shape; the schedule
+    (``xs``) is an argument, so programs of one length share it."""
+    key = (n_dev, per, n_sms, cfg, backend, n_steps)
+    hit = _FLEET_PROGRAMS.get(key)
+    if hit is not None:
+        return hit
     mesh = make_fleet_mesh(n_dev)
     spec = fleet_spec()
 
-    def body(bidx, pidx, regs, sh, gmem, oob):
-        bidx, pidx = bidx[0], pidx[0]
-        regs, sh, gmem, oob = regs[0], sh[0], gmem[0], oob[0]
-        # the device's waves run back to back sharing its gmem replica —
+    def body(xs, bidx, pidx, sh, gmem):
+        bidx, pidx, sh = bidx[0], pidx[0], sh[0]
+        regs, shs, oobs = [], [], []
         # the same chunking as the single-device homogeneous path
         for w0 in range(0, per, n_sms):
             w1 = min(w0 + n_sms, per)
             r, s, gmem, o = trace_engine._run_schedule(
-                cfg, backend, sched.xs, bidx[w0:w1], pidx[w0:w1],
-                regs[w0:w1], sh[w0:w1], gmem, oob[w0:w1])
-            regs = regs.at[w0:w1].set(r)
-            sh = sh.at[w0:w1].set(s)
-            oob = oob.at[w0:w1].set(o)
-        return regs[None], sh[None], gmem[None], oob[None]
+                cfg, backend, xs, bidx[w0:w1], pidx[w0:w1],
+                jnp.zeros((w1 - w0, MAX_THREADS, N_REGS), _U32),
+                sh[w0:w1], gmem, jnp.zeros((w1 - w0,), jnp.bool_))
+            regs.append(r)
+            shs.append(s)
+            oobs.append(o)
+        return (jnp.concatenate(regs)[None], jnp.concatenate(shs)[None],
+                gmem[None], jnp.concatenate(oobs)[None])
 
     # check_vma=False: the Pallas backend's kernels declare plain output
     # shapes, with no varying-manual-axes annotation to check
-    regs, sh, gmems, oob = jax.shard_map(
-        body, mesh=mesh, in_specs=(spec,) * 6, out_specs=(spec,) * 4,
-        check_vma=False)(
-            bid, pid, regs0, sh0, gm0, oob0)
-    # the JAX device holding each simulated device's slice, in fleet order
-    shards = sorted(regs.addressable_shards,
-                    key=lambda s: s.index[0].start or 0)
-    devices = [int(s.device.id) for s in shards]
-    return order, regs, sh, gmems, oob, sched.halted, devices
+    program = jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=(P(), spec, spec, spec, P()),
+        out_specs=(spec,) * 4, check_vma=False))
+    hit = (program, NamedSharding(mesh, spec), NamedSharding(mesh, P()))
+    _FLEET_PROGRAMS[key] = hit
+    return hit
+
+
+def _host_u32(arr, depth: int, what: str) -> np.ndarray:
+    """``machine.as_u32_image`` on the host: the same words, in numpy."""
+    a = np.asarray(arr)
+    a = np.ascontiguousarray(a, jax.dtypes.canonicalize_dtype(a.dtype))
+    a = a.view(np.uint32) if a.dtype == np.float32 else a.astype(np.uint32)
+    pad = depth - a.shape[-1]
+    if pad < 0:
+        raise ValueError(f"{what} image of {a.shape[-1]} words exceeds "
+                         f"depth {depth}")
+    if pad:
+        a = np.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)])
+    return a
+
+
+def _stage_shard_map(fcfg: FleetConfig, backend: str, cfg, words,
+                     local_bid, device_of, shmem, gm):
+    """Everything the mapped call takes, each device's slice placed from
+    the host straight onto its own JAX device: ``(program, args, order,
+    sched)``, ``order`` the grid blocks in device-major order."""
+    n_dev = fcfg.n_devices
+    n_blocks = local_bid.shape[0]
+    per = n_blocks // n_dev
+    sched = trace_engine.compile_program(words, cfg)
+    program, sharded, replicated = _fleet_program(
+        n_dev, per, fcfg.device.n_sms, cfg, backend, sched.n_steps)
+    # route="block" on a single phase is contiguous by construction
+    order = np.concatenate([np.flatnonzero(device_of == d)
+                            for d in range(n_dev)])
+    depth = cfg.shmem_depth
+    if shmem is None:
+        sh = np.zeros((n_blocks, depth), np.uint32)
+    else:
+        batch = _host_u32(shmem, depth, "shared-memory (program 0)")
+        if batch.ndim == 1:
+            batch = np.broadcast_to(batch, (n_blocks, depth))
+        elif batch.shape[0] != n_blocks:
+            raise ValueError(f"shared-memory batch of {batch.shape[0]} "
+                             f"images != {n_blocks} blocks of program 0")
+        sh = batch[local_bid[order]]
+    bid, pid, sh = jax.device_put(
+        (local_bid[order].astype(np.int32).reshape(n_dev, per),
+         np.zeros((n_dev, per), np.int32), sh.reshape(n_dev, per, depth)),
+        sharded)
+    key = (id(sched), replicated)
+    if key not in _PLACED_XS:   # the value keeps sched, so no id recurs
+        _PLACED_XS[key] = (sched, jax.device_put(sched.xs, replicated))
+    xs = _PLACED_XS[key][1]
+    gm0 = jax.device_put(np.asarray(gm), replicated)
+    return program, (xs, bid, pid, sh, gm0), order, sched
 
 
 def launch_fleet(fcfg: FleetConfig, program=None, grid=None,
@@ -295,55 +337,65 @@ def launch_fleet(fcfg: FleetConfig, program=None, grid=None,
         }
         return res
 
-    # ---- normalize + lower exactly like the single device ---------------
-    kernels, gmap, shmems = _normalize_grid(dcfg, program, grid, block,
-                                            dim_x, programs, grid_map,
-                                            shmem)
-    n_blocks = int(gmap.shape[0])
-    backend = backend or dcfg.backend
-    mode = _resolve_schedule(schedule, dcfg, len(kernels))
-    compile_cache.configure_jax_cache()
-    names, cfgs, imems, traces, word_arrays = _lower_kernels(dcfg, kernels)
-    eng, eng_fallback = _resolve_engine(engine, dcfg, traces)
+    with tracing.span("egpu.launch_fleet"):
+        return _launch_fleet(fcfg, program, grid, block, programs,
+                             grid_map, buffers, shmem, gmem, backend, dim_x,
+                             schedule, engine, packing, queue_depth)
 
-    if queue_depth < 0:
-        raise ValueError(f"queue_depth={queue_depth} must be >= 0")
-    host_latency = dcfg.dispatch_latency + dcfg.queue_latency * queue_depth
-    host_dispatch = None
-    if dcfg.dispatch_latency or dcfg.queue_latency:
-        host_dispatch = {
-            "queue_depth": int(queue_depth),
-            "dispatch_cycles": int(dcfg.dispatch_latency),
-            "queue_cycles": int(dcfg.queue_latency * queue_depth),
-            "latency_cycles": int(host_latency),
-        }
 
-    phase_of_kernel = np.cumsum([int(k.barrier) for k in kernels])
-    block_phase = phase_of_kernel[gmap]
-    device_of = _route_blocks(fcfg, gmap, block_phase)
-    local_bid = np.zeros(n_blocks, np.int64)
-    for k in range(len(kernels)):
-        pos = np.flatnonzero(gmap == k)
-        local_bid[pos] = np.arange(pos.size)
-    placement, placement_reason = _resolve_placement(
-        fcfg, kernels, gmap, block_phase, traces, eng)
+def _launch_fleet(fcfg: FleetConfig, program, grid, block, programs,
+                  grid_map, buffers, shmem, gmem, backend, dim_x, schedule,
+                  engine, packing, queue_depth) -> LaunchResult:
+    """``launch_fleet`` on two or more devices, one span per phase."""
+    dcfg = fcfg.device
+    # ---- plan: normalize + lower exactly like the single device, route,
+    # place --------------------------------------------------------------
+    with tracing.span("egpu.fleet.plan"):
+        kernels, gmap, shmems = _normalize_grid(dcfg, program, grid, block,
+                                                dim_x, programs, grid_map,
+                                                shmem)
+        n_blocks = int(gmap.shape[0])
+        backend = backend or dcfg.backend
+        mode = _resolve_schedule(schedule, dcfg, len(kernels))
+        compile_cache.configure_jax_cache()
+        names, cfgs, imems, traces, word_arrays = _lower_kernels(dcfg,
+                                                                 kernels)
+        eng, eng_fallback = _resolve_engine(engine, dcfg, traces)
 
-    # ---- global-memory image --------------------------------------------
-    offsets = None
-    if buffers is not None:
-        if gmem is not None:
-            raise ValueError("pass either buffers= or gmem=, not both")
-        gm, offsets = pack_buffers(buffers, dcfg.global_mem_depth)
-    elif gmem is not None:
-        gm = as_u32_image(gmem, dcfg.global_mem_depth, "global-memory")
-    else:
-        gm = jnp.zeros((dcfg.global_mem_depth,), _U32)
+        if queue_depth < 0:
+            raise ValueError(f"queue_depth={queue_depth} must be >= 0")
+        host_latency = dcfg.dispatch_latency \
+            + dcfg.queue_latency * queue_depth
+        host_dispatch = None
+        if dcfg.dispatch_latency or dcfg.queue_latency:
+            host_dispatch = {
+                "queue_depth": int(queue_depth),
+                "dispatch_cycles": int(dcfg.dispatch_latency),
+                "queue_cycles": int(dcfg.queue_latency * queue_depth),
+                "latency_cycles": int(host_latency),
+            }
 
-    # fleet-level per-kernel shmem batches (program-local block order)
-    counts = [int((gmap == k).sum()) for k in range(len(kernels))]
-    sh_batches = [_kernel_shmem(shmems[k], cfgs[k].shmem_depth,
-                                counts[k], k) if counts[k] else None
-                  for k in range(len(kernels))]
+        phase_of_kernel = np.cumsum([int(k.barrier) for k in kernels])
+        block_phase = phase_of_kernel[gmap]
+        device_of = _route_blocks(fcfg, gmap, block_phase)
+        local_bid = np.zeros(n_blocks, np.int64)
+        for k in range(len(kernels)):
+            pos = np.flatnonzero(gmap == k)
+            local_bid[pos] = np.arange(pos.size)
+        placement, placement_reason = _resolve_placement(
+            fcfg, kernels, gmap, block_phase, traces, eng)
+
+    # ---- stage: the global-memory image ---------------------------------
+    with tracing.span("egpu.fleet.stage"):
+        offsets = None
+        if buffers is not None:
+            if gmem is not None:
+                raise ValueError("pass either buffers= or gmem=, not both")
+            gm, offsets = pack_buffers(buffers, dcfg.global_mem_depth)
+        elif gmem is not None:
+            gm = as_u32_image(gmem, dcfg.global_mem_depth, "global-memory")
+        else:
+            gm = jnp.zeros((dcfg.global_mem_depth,), _U32)
 
     # ---- functional execution -------------------------------------------
     regs_slots: list[Any] = [None] * n_blocks
@@ -354,35 +406,49 @@ def launch_fleet(fcfg: FleetConfig, program=None, grid=None,
     sub_engine = eng
     shard_devices = None
     if placement == "shard_map":
-        (order, regs_d, sh_d, gmems_d, oob_d, sm_halted,
-         shard_devices) = _run_shard_map(
-            fcfg, backend, cfgs[0], word_arrays[0], gmap, local_bid,
-            device_of, sh_batches[0], gm)
+        with tracing.span("egpu.fleet.stage"):
+            mapped, args, order, sched = _stage_shard_map(
+                fcfg, backend, cfgs[0], word_arrays[0], local_bid,
+                device_of, shmems[0], gm)
+        with tracing.span("egpu.fleet.dispatch"):
+            regs_d, sh_d, gmems_d, oob_d = mapped(*args)
         # per-device replicas diff-merge against the launch image in
         # device order — exact under the no-race launch contract
-        merged = gm
-        for d in range(fcfg.n_devices):
-            changed = gmems_d[d] != gm
-            merged = jnp.where(changed, gmems_d[d], merged)
-        gm = merged
-        per = n_blocks // fcfg.n_devices
-        flat_regs = regs_d.reshape(n_blocks, MAX_THREADS, N_REGS)
-        flat_sh = sh_d.reshape(n_blocks, -1)
-        flat_oob = oob_d.reshape(n_blocks)
-        for i, b in enumerate(order):
-            regs_slots[b] = flat_regs[i]
-            shmem_slots[b] = flat_sh[i]
-            oob_slots[b] = flat_oob[i]
-        if flat_sh.shape[1] < shmem_pad:
-            pad = shmem_pad - flat_sh.shape[1]
-            for b in range(n_blocks):
-                shmem_slots[b] = jnp.pad(shmem_slots[b], (0, pad))
-        halted = bool(sm_halted)
+        with tracing.span("egpu.fleet.merge"):
+            merged = gm
+            for d in range(fcfg.n_devices):
+                changed = gmems_d[d] != gm
+                merged = jnp.where(changed, gmems_d[d], merged)
+            gm = merged
+        with tracing.span("egpu.fleet.unpack"):
+            flat_regs = regs_d.reshape(n_blocks, MAX_THREADS, N_REGS)
+            flat_sh = sh_d.reshape(n_blocks, -1)
+            flat_oob = oob_d.reshape(n_blocks)
+            for i, b in enumerate(order):
+                regs_slots[b] = flat_regs[i]
+                shmem_slots[b] = flat_sh[i]
+                oob_slots[b] = flat_oob[i]
+            if flat_sh.shape[1] < shmem_pad:
+                pad = shmem_pad - flat_sh.shape[1]
+                for b in range(n_blocks):
+                    shmem_slots[b] = jnp.pad(shmem_slots[b], (0, pad))
+            # the JAX device holding each simulated device's slice, in
+            # fleet order
+            shards = sorted(regs_d.addressable_shards,
+                            key=lambda s: s.index[0].start or 0)
+            shard_devices = [int(s.device.id) for s in shards]
+        halted = bool(sched.halted)
         sub_engine = "trace"        # the mapped body runs the scanned
         eng_fallback = None         # schedule; engines are bit-identical
     else:
         # host path: phase-by-phase, per-device sub-launches against the
         # phase's base gmem, diff-merged in device order
+        with tracing.span("egpu.fleet.stage"):
+            # fleet-level per-kernel shmem batches (program-local order)
+            counts = [int((gmap == k).sum()) for k in range(len(kernels))]
+            sh_batches = [_kernel_shmem(shmems[k], cfgs[k].shmem_depth,
+                                        counts[k], k) if counts[k] else None
+                          for k in range(len(kernels))]
         for p in np.unique(block_phase):
             pblocks = np.flatnonzero(block_phase == p)
             base = gm
@@ -391,30 +457,94 @@ def launch_fleet(fcfg: FleetConfig, program=None, grid=None,
                 bd = pblocks[device_of[pblocks] == d]
                 if bd.size == 0:
                     continue
-                sub_shmems: list[Any] = []
-                for k in range(len(kernels)):
-                    batch = sh_batches[k]
-                    mine = bd[gmap[bd] == k]
-                    if batch is None or mine.size == 0:
-                        sub_shmems.append(None)
-                    else:
-                        sub_shmems.append(np.asarray(
-                            batch[local_bid[mine]]))
-                sub = launch(dcfg, programs=kernels,
-                             grid_map=gmap[bd], shmem=sub_shmems,
-                             gmem=base, backend=backend, schedule=mode,
-                             engine=sub_engine, packing=packing,
-                             block_ids=local_bid[bd])
-                changed = sub.gmem != base
-                merged = jnp.where(changed, sub.gmem, merged)
-                for i, b in enumerate(bd):
-                    regs_slots[b] = sub.regs[i]
-                    shmem_slots[b] = sub.shmem[i]
-                    oob_slots[b] = sub.oob[i]
+                with tracing.span("egpu.fleet.stage"):
+                    sub_shmems: list[Any] = []
+                    for k in range(len(kernels)):
+                        batch = sh_batches[k]
+                        mine = bd[gmap[bd] == k]
+                        if batch is None or mine.size == 0:
+                            sub_shmems.append(None)
+                        else:
+                            sub_shmems.append(np.asarray(
+                                batch[local_bid[mine]]))
+                with tracing.span("egpu.fleet.dispatch"):
+                    sub = launch(dcfg, programs=kernels,
+                                 grid_map=gmap[bd], shmem=sub_shmems,
+                                 gmem=base, backend=backend, schedule=mode,
+                                 engine=sub_engine, packing=packing,
+                                 block_ids=local_bid[bd])
+                with tracing.span("egpu.fleet.merge"):
+                    changed = sub.gmem != base
+                    merged = jnp.where(changed, sub.gmem, merged)
+                with tracing.span("egpu.fleet.unpack"):
+                    for i, b in enumerate(bd):
+                        regs_slots[b] = sub.regs[i]
+                        shmem_slots[b] = sub.shmem[i]
+                        oob_slots[b] = sub.oob[i]
                 halted = halted and sub.halted
             gm = merged
+    with tracing.span("egpu.fleet.unpack"):
+        regs = jnp.stack(regs_slots, axis=0)
+        shmem_out = jnp.stack(shmem_slots, axis=0)
+        oob = jnp.stack(oob_slots, axis=0)
 
-    # ---- fleet timing: per-device schedules, merged ----------------------
+    with tracing.span("egpu.fleet.schedule"):
+        (timing, static_span, by_class, steps, remote_gmem_cycles,
+         per_device, resolved_packing) = _fleet_timing(
+            fcfg, kernels, traces, gmap, block_phase, device_of, mode,
+            packing, host_latency)
+    fleet_info = {
+        "n_devices": int(fcfg.n_devices),
+        "route": fcfg.route,
+        "placement": placement,
+        "placement_reason": placement_reason,
+        "remote_gmem_latency": int(fcfg.remote_gmem_latency),
+        "remote_gmem_cycles": int(remote_gmem_cycles),
+        "per_device": per_device,
+    }
+    if shard_devices is not None:
+        fleet_info["shard_devices"] = shard_devices
+
+    return LaunchResult(
+        grid=(n_blocks,),
+        block=cfgs[0].n_threads if len(kernels) == 1
+        else tuple(c.n_threads for c in cfgs),
+        n_waves=len(timing.wave_cycles),
+        regs=regs,
+        shmem=shmem_out,
+        gmem=gm,
+        oob=oob,
+        halted=halted,
+        steps=int(steps),
+        cycles=int(timing.makespan),
+        wave_cycles=np.asarray(timing.wave_cycles, np.int64),
+        cycles_by_class=by_class.astype(np.int64),
+        buffer_offsets=offsets,
+        schedule=mode,
+        engine=sub_engine,
+        engine_fallback=eng_fallback,
+        program_names=tuple(names),
+        grid_map=gmap,
+        timing=timing,
+        static_cycles=int(static_span),
+        trace_merge=None,
+        packing=resolved_packing,
+        wave_packing=None,
+        host_dispatch=host_dispatch,
+        priority_respected=(mode == "dynamic")
+        or not any(k.priority for k in kernels),
+        fleet=fleet_info,
+    )
+
+
+def _fleet_timing(fcfg: FleetConfig, kernels, traces, gmap, block_phase,
+                  device_of, mode: str, packing, host_latency: int):
+    """The fleet's cycle model: per-device schedules of each phase merged
+    into the fleet view (and the static re-run), the aggregate counters,
+    and ``profile()["fleet"]`` less the placement. Returns ``(timing,
+    static makespan, cycles by class, steps, fleet info, packing)``."""
+    dcfg = fcfg.device
+    n_blocks = int(gmap.shape[0])
     lat = int(fcfg.remote_gmem_latency)
     remote_traces = [_remote_trace(t, lat) for t in traces]
 
@@ -482,45 +612,5 @@ def launch_fleet(fcfg: FleetConfig, program=None, grid=None,
             "makespan": dev_finish,
         })
 
-    fleet_info = {
-        "n_devices": int(fcfg.n_devices),
-        "route": fcfg.route,
-        "placement": placement,
-        "placement_reason": placement_reason,
-        "remote_gmem_latency": lat,
-        "remote_gmem_cycles": int(remote_gmem_cycles),
-        "per_device": per_device,
-    }
-    if shard_devices is not None:
-        fleet_info["shard_devices"] = shard_devices
-
-    return LaunchResult(
-        grid=(n_blocks,),
-        block=cfgs[0].n_threads if len(kernels) == 1
-        else tuple(c.n_threads for c in cfgs),
-        n_waves=len(timing.wave_cycles),
-        regs=jnp.stack(regs_slots, axis=0),
-        shmem=jnp.stack(shmem_slots, axis=0),
-        gmem=gm,
-        oob=jnp.stack(oob_slots, axis=0),
-        halted=halted,
-        steps=int(steps),
-        cycles=int(timing.makespan),
-        wave_cycles=np.asarray(timing.wave_cycles, np.int64),
-        cycles_by_class=by_class.astype(np.int64),
-        buffer_offsets=offsets,
-        schedule=mode,
-        engine=sub_engine,
-        engine_fallback=eng_fallback,
-        program_names=tuple(names),
-        grid_map=gmap,
-        timing=timing,
-        static_cycles=int(static_span),
-        trace_merge=None,
-        packing=resolved_packing,
-        wave_packing=None,
-        host_dispatch=host_dispatch,
-        priority_respected=(mode == "dynamic")
-        or not any(k.priority for k in kernels),
-        fleet=fleet_info,
-    )
+    return (timing, static_span, by_class, steps, remote_gmem_cycles,
+            per_device, resolved_packing)
